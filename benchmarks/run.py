@@ -1,5 +1,5 @@
 """Benchmark harness — one function per paper table/figure, plus kernel
-micro-benchmarks and the roofline summary.
+micro-benchmarks.
 
 Prints ``name,value,derived`` CSV rows (value unit depends on the bench;
 latency rows are milliseconds, throughput rows ops/s) and mirrors every
@@ -10,7 +10,7 @@ machine-readable across PRs.
 written back; instead every deterministic (virtual-time) row is compared
 against the committed BENCH_*.json baselines within a tolerance band and
 the process exits non-zero on any out-of-band metric (host-dependent
-rows — walltimes, speedups, microsecond timings, roofline — are reported
+rows — walltimes, speedups, microsecond timings — are reported
 but never gate).  The full report lands in ``BENCH_check_report.json``.
 """
 from __future__ import annotations
@@ -209,8 +209,9 @@ def bench_closed_sweep():
     batched fixed-point program (repro.sim.sweep, loop="closed") vs
     looping the numpy fast engine over the same grid, plus the
     per-device scaling of the sharded program.  Each device count runs
-    in its own subprocess because XLA fixes the host platform device
-    count at first jax init."""
+    in its own CPU-only subprocess (``JAX_PLATFORMS=cpu``): XLA fixes the
+    host platform device count at first jax init, and on a TPU host the
+    parent already holds the chip."""
     import os
     import subprocess
 
@@ -264,21 +265,20 @@ def bench_closed_sweep():
         "t0 = walltime()\n"
         "run_sweep(grid, loop='closed', seed=0, devices=d)\n"
         "print(json.dumps(dict(devices=d,"
+        " platform=jax.devices()[0].platform,"
         " warm_s=walltime() - t0)))\n")
     src = str(Path(__file__).resolve().parent.parent / "src")
     for d in (1, 2, 4, 8):
         env = dict(
-            os.environ, PYTHONPATH=src,
+            os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
             XLA_FLAGS=f"--xla_force_host_platform_device_count={d}")
-        try:
-            out = subprocess.run(
-                [sys.executable, "-c", child % d], env=env, text=True,
-                capture_output=True, timeout=600, check=True)
-            r = json.loads(out.stdout.strip().splitlines()[-1])
-            _row(f"sim.per_device_scaling.d{d}", f"{r['warm_s']:.2f}",
-                 f"devices={r['devices']};points={len(grid)};warm run")
-        except Exception as e:  # pragma: no cover - bench robustness
-            _row(f"sim.per_device_scaling.d{d}", "nan", str(e)[:80])
+        out = subprocess.run(
+            [sys.executable, "-c", child % d], env=env, text=True,
+            capture_output=True, timeout=600, check=True)
+        r = json.loads(out.stdout.strip().splitlines()[-1])
+        _row(f"sim.per_device_scaling.d{d}", f"{r['warm_s']:.2f}",
+             f"devices={r['devices']};platform={r['platform']};"
+             f"points={len(grid)};warm run")
     _write_json()
 
 
@@ -657,27 +657,11 @@ def bench_edgecache():
          "fraction of global pages served from dedup")
 
 
-# ------------------------------------------------------ roofline summary
-def bench_roofline():
-    sys.path.insert(0, str(Path(__file__).resolve().parent))
-    import roofline
-    try:
-        rows = roofline.main(out_md=str(
-            Path(__file__).resolve().parent / "roofline_table.md"))
-    except Exception as e:
-        _row("roofline.error", "0", str(e)[:80])
-        return
-    for r in rows:
-        _row(f"roofline.{r['arch']}.{r['shape']}.{r['mesh']}",
-             f"{max(r['compute_s'], r['memory_s'], r['collective_s']):.4f}",
-             f"bottleneck={r['bottleneck']};frac={r['roofline_frac']:.2f}")
-
-
 # Substrings marking host-dependent rows: mirrored into the check report
 # for eyeballing, but never allowed to fail the regression gate (they
 # measure this machine, not the simulation).
 _UNGATED = ("walltime", "speedup", "_us", "per_device_scaling",
-            "roofline", "kernel.", "compile")
+            "kernel.", "compile")
 
 
 def _gated(name: str) -> bool:
@@ -748,6 +732,8 @@ def main(argv=None) -> int:
                          "(default 0.05)")
     args = ap.parse_args(argv)
     _CHECK_MODE = args.check
+    from repro.jaxcache import use_compile_cache
+    use_compile_cache()
     print("name,value,derived")
     bench_core_protocol()
     bench_kernels()
@@ -771,7 +757,6 @@ def main(argv=None) -> int:
     _timed("fig9_10", bench_fig9_10_clients_local)
     _timed("fig11_12", bench_fig11_12_clients_global)
     _timed("fig13", bench_fig13_rate)
-    bench_roofline()
     _write_json()
     if args.check:
         return run_check(args.tolerance)

@@ -72,8 +72,8 @@ print(f"emulated 300 clients x YCSB-A at 50% global: "
 # --- parameter sweeps: a whole what-if grid as one array program --------
 # EdgeKV's evaluation is a grid of scenarios; run_sweep evaluates a
 # p_global x contention x rate x groups grid in a single jitted call
-# (scan_backend="pallas" routes the departure scan through the TPU
-# kernel; interpret mode off-TPU).
+# (scan_backend="pallas" with interpret=True routes the departure scan
+# through the Pallas kernel in interpret mode).
 from repro.sim import SweepPoint, run_sweep
 
 grid = [SweepPoint(p_global=pg, rate=rate, groups=3)

@@ -24,7 +24,7 @@ the same trace and are covered by walking the outermost root.
   ``.size``, ``len()``, ``isinstance()``).
 * **EDK104** — ``float64`` requests outside the x64 guard
   (``jnp.float64`` / ``astype("float64")`` / ``dtype="float64"`` in jax
-  calls): without ``jax.experimental.enable_x64`` (or the
+  calls): without ``jax.enable_x64`` (or the
   ``jax_enable_x64`` config flag) jax silently truncates to float32 and
   the <2% cross-engine figures drift.
 """

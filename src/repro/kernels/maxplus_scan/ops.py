@@ -12,8 +12,10 @@ Three interchangeable evaluations of ``d_i = max(a_i, d_{i-1}) + s_i``:
   just ``m = -inf`` (the map forgets its input), so segmented scans need
   no extra machinery.  This is the backend the sweep engine jits and
   ``vmap``s over whole parameter grids.
-* ``pallas`` — the TPU kernel in ``kernel.py`` (sequential chunk grid,
-  VMEM carry), run in interpret mode off-TPU.
+* ``pallas`` — the kernel in ``kernel.py`` (sequential chunk grid,
+  VMEM carry).  It runs only in interpret mode (``interpret=True``): the
+  TPU compiler refuses it (no Pallas TPU lowering of its in-chunk
+  ``cumsum``, and ``block_rows=1`` breaks the (8, 128) block rule).
 
 ``backend="auto"`` picks ``numpy`` for concrete numpy inputs and
 ``assoc`` for jax arrays/tracers, so the same call site works inside and
@@ -40,10 +42,14 @@ def _assoc(arrive, svc, reset, init):
     svc = jnp.asarray(svc, arrive.dtype)
     if reset is None:
         # closed form: two single-array associative scans (cumsum +
-        # cummax) instead of one over (m, c) pairs — half the scan work
+        # cummax) instead of one over (m, c) pairs — half the scan work.
+        # Spelled as explicit associative scans: the TPU compiler lowers
+        # jnp.cumsum / lax.cummax to a window reduction, which in
+        # (emulated) float64 does not finish compiling at L ~ 1000
         ax = arrive.ndim - 1
-        S = jnp.cumsum(svc, axis=ax)
-        z = jax.lax.cummax(arrive - (S - svc), axis=ax)
+        S = jax.lax.associative_scan(jnp.add, svc, axis=ax)
+        z = jax.lax.associative_scan(jnp.maximum, arrive - (S - svc),
+                                     axis=ax)
         if init is not None:
             x0 = jnp.asarray(init, arrive.dtype)
             z = jnp.maximum(z, x0[..., None] if x0.ndim else x0)
@@ -91,7 +97,7 @@ def _numpy_seg(a, s, init):
 def maxplus_depart(arrive, svc, reset=None, *, init=None,
                    backend: str = "auto", chunk: int = 256,
                    block_rows: int = 1,
-                   interpret: bool | None = None):
+                   interpret: bool = False):
     """Departure times for the leader-stage recurrence.  (..., L) in,
     (..., L) out; see module docstring for the backends.
 
@@ -117,8 +123,6 @@ def maxplus_depart(arrive, svc, reset=None, *, init=None,
         raise NotImplementedError(
             "the pallas backend segments by row; pre-split sequences into "
             "rows instead of passing reset")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     a = jnp.asarray(arrive)
     s = jnp.asarray(svc, a.dtype)
     shape = a.shape

@@ -10,7 +10,7 @@ absent from the result dict.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 from .clock import walltime
 
@@ -55,7 +55,7 @@ def profile_compile(fn: Callable, *args: Any,
         "compile_s": t2 - t1,
         "traces": float(counter.traces),
     }
-    cost = _first_dict(_maybe(compiled.cost_analysis))
+    cost = _maybe(compiled.cost_analysis)
     if cost:
         for src, dst in (("flops", "flops"),
                          ("bytes accessed", "bytes_accessed")):
@@ -76,20 +76,19 @@ def profile_compile(fn: Callable, *args: Any,
 
 def profile_maxplus(n: int = 4096, rows: int = 8,
                     backend: str = "assoc",
-                    interpret: Optional[bool] = None) -> Dict[str, float]:
+                    interpret: bool = False) -> Dict[str, float]:
     """Profile one ``maxplus_depart`` configuration (the sweep engine's
     hot kernel) at a representative ``(rows, n)`` scan shape — under
-    ``enable_x64``, the regime every sweep call traces in."""
+    ``jax.enable_x64``, the regime every sweep call traces in."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.kernels.maxplus_scan import maxplus_depart
 
     def run(a, s):
-        extra = {} if interpret is None else {"interpret": interpret}
-        return maxplus_depart(a, s, backend=backend, **extra)
+        return maxplus_depart(a, s, backend=backend, interpret=interpret)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         arrive = jnp.linspace(0.0, 1.0, rows * n,
                               dtype=jnp.float64).reshape(rows, n)
         svc = jnp.full((rows, n), 1e-4, dtype=jnp.float64)
@@ -103,11 +102,3 @@ def _maybe(fn: Callable) -> Any:
         return fn()
     except Exception:      # backend without analysis support
         return None
-
-
-def _first_dict(cost: Any) -> Optional[dict]:
-    # cost_analysis historically returned [dict] per computation;
-    # newer jax returns the dict directly
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else None
-    return cost if isinstance(cost, dict) else None

@@ -25,7 +25,7 @@ extends the resolved wavefront by at least one op per thread and the
 iteration converges — bitwise — in O(ops-per-thread) rounds.  The true
 schedule is a fixed point of the round map, so extra rounds are no-ops;
 that is what makes the multi-device program (``devices=N`` shards the
-point axis with ``jax.shard_map``, ``pmap`` fallback) bit-identical to
+point axis with ``jax.shard_map``) bit-identical to
 the single-device one even though shards converge at different rounds.
 
 Layout: the grid is flattened to **one row per (config, serving group)**
@@ -56,38 +56,34 @@ Exactness: every per-point result matches an independent
 ``SimEdgeKV(engine="fast")`` run on the same seeds to ~1e-13 relative —
 the array program evaluates the identical float64 expressions; only the
 scan/reduction association order differs.  The jitted call runs under
-``jax.experimental.enable_x64`` so float64 survives jax.
+``jax.enable_x64`` so float64 survives jax.  The closed loop's default
+``seq`` path goes further: it carries every time as the int64 bit
+pattern of its IEEE double (:mod:`repro.sim.f64bits`), because with the
+TPU's emulated float64 it missed the 1e-9 contract on a v5e.
 """
 from __future__ import annotations
 
-from dataclasses import asdict, dataclass
-from functools import lru_cache
+import operator
+from dataclasses import asdict, dataclass, field
+from functools import lru_cache, partial
 from itertools import product
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.hashring import ChordRing, stable_hash
 from repro.kernels.maxplus_scan import maxplus_depart
 from repro.obs import walltime
 from repro.obs.trace import STAGES as OBS_STAGES
 
+from . import f64bits
 from .cluster import ServiceParams, arrival_seed, closed_loop_plan
 from .network import SETTINGS
 from .vectorized import (GLOBAL_CODE, READ_CODE, _DelayModel,
                          _open_loop_segments, arrival_chain,
                          completion_chain, lru_hit_mask, plan_columns)
-
-try:  # moved between jax versions; the sweep degrades to pmap without it
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    try:
-        from jax import shard_map  # type: ignore[attr-defined]
-    except ImportError:
-        shard_map = None
 
 _PAIRS = ("c_req", "c_resp", "f_req", "f_resp", "sg_req", "sg_resp",
           "h_req", "g_resp", "svc_base")
@@ -152,6 +148,12 @@ class SweepResult:
     points: List[SweepPoint]
     columns: Dict[str, np.ndarray]
     walltime_s: float = 0.0
+    # how the grid ran: ``path`` ("device", or "host" for the eviction
+    # regime's host fixed point), ``device_s`` (walltime from the
+    # host->device transfer through the final ``device_get``, compile
+    # included on a cold call), and for the closed loop ``devices`` and
+    # the fixed point's ``rounds`` (max over shards)
+    info: Dict[str, object] = field(default_factory=dict)
 
     def __len__(self) -> int:
         return len(self.points)
@@ -328,7 +330,7 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
               setting: str = "edge", seed: int = 0,
               service: Optional[ServiceParams] = None,
               virtual_nodes: int = 1, scan_backend: Optional[str] = None,
-              interpret: Optional[bool] = None,
+              interpret: bool = False,
               percentiles: Sequence[float] = (95.0, 99.0),
               loop: str = "open", devices: int = 1,
               max_rounds: Optional[int] = None) -> SweepResult:
@@ -349,8 +351,8 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     ``seed_offset``, so ``seed`` plays that role here; ``duration`` and
     ``p.rate`` are ignored).  The whole grid runs as one batched
     fixed-point iteration (see the module docstring), sharded over the
-    point axis with ``devices`` > 1 (``jax.shard_map``, ``pmap``
-    fallback; on CPU raise the device count with
+    point axis with ``devices`` > 1 (``jax.shard_map``; on CPU raise
+    the device count with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
     ``max_rounds`` caps the fixed-point iteration (default: generous in
     ops-per-thread); non-convergence raises instead of returning wrong
@@ -364,7 +366,10 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     resolves per loop mode: ``"assoc"`` (``jax.lax.associative_scan``,
     closed-form) for open loop, ``"seq"`` (``lax.scan``, the engine's
     exact sequential float association) for closed loop.  ``"pallas"``
-    uses the TPU kernel, batched over rows (interpret mode off-TPU).
+    uses the Pallas kernel, batched over rows; it runs only with
+    ``interpret=True``, because the sweep feeds it float64 and the
+    kernel does not compile for the TPU (no float64, no in-kernel
+    ``cumsum`` lowering), so ``interpret=False`` raises.
     The closed loop defaults to ``"seq"`` because its fixed point feeds
     completions back into *queue ordering*: the closed-form scans
     reassociate float adds, and a 1-ulp deviation can flip the order of
@@ -389,6 +394,11 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
         raise ValueError(f"unknown scan_backend {scan_backend!r}")
     if loop == "open" and scan_backend == "seq":
         raise ValueError("scan_backend='seq' is closed-loop only")
+    if scan_backend == "pallas" and not interpret:
+        raise ValueError(
+            "scan_backend='pallas' needs interpret=True: the sweep runs "
+            "in float64, and the Pallas max-plus kernel does not compile "
+            "for the TPU (no float64, no cumsum lowering)")
     if loop == "closed":
         return _run_closed(points, setting=setting, seed=seed,
                            service=service, virtual_nodes=virtual_nodes,
@@ -398,12 +408,90 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     if devices != 1:
         raise ValueError("devices > 1 requires loop='closed'")
     t_wall = walltime()
+    qs = tuple(float(q) for q in percentiles)
+    ob = _open_build(points, duration=duration, setting=setting, seed=seed,
+                     service=service, virtual_nodes=virtual_nodes)
+    per, flat, gidx = ob["per"], ob["flat"], ob["gidx"]
+    n_total, row_tbl_arr = ob["n_total"], ob["row_tbl"]
+    valid = gidx < n_total
+
+    # ---- the single jitted call ----
+    fn = _compiled(ob["max_hops"], scan_backend, bool(interpret))
+    with jax.enable_x64(True):
+        t_dev = walltime()
+        cnt4, sum4, lat_rows, stage_sum = jax.device_get(fn(
+            *jax.tree.map(jnp.asarray, _open_args(ob))))
+        device_s = walltime() - t_dev
+
+    # ---- fold rows back into per-point RecordArray-style aggregates ----
+    lat_op = np.empty(n_total)
+    lat_op[gidx[valid]] = np.asarray(lat_rows)[valid]
+    cnt4 = np.asarray(cnt4, np.float64)
+    sum4 = np.asarray(sum4)
+    N = len(points)
+    cnt_pt = np.zeros((N, 4))
+    sum_pt = np.zeros((N, 4))
+    for c in range(4):
+        cnt_pt[:, c] = np.bincount(row_tbl_arr, cnt4[:, c], minlength=N)
+        sum_pt[:, c] = np.bincount(row_tbl_arr, sum4[:, c], minlength=N)
+
+    # categories: (read-local, read-global, update-local, update-global)
+    sel = {"mean_latency": (0, 1, 2, 3), "read_latency": (0, 1),
+           "update_latency": (2, 3), "local_latency": (0, 2),
+           "global_latency": (1, 3), "update_global_latency": (3,)}
+    cols: Dict[str, np.ndarray] = {
+        "ops": np.asarray([d["n"] for d in per], np.int64)}
+    for name, cats in sel.items():
+        c = cnt_pt[:, list(cats)].sum(axis=1)
+        s = sum_pt[:, list(cats)].sum(axis=1)
+        cols[name] = np.where(c > 0, s / np.maximum(c, 1), np.nan)
+
+    # per-point per-stage mean durations (span model, program aggregates)
+    n_ops_pt = cnt_pt.sum(axis=1)
+    stage_sum = np.asarray(stage_sum, np.float64)
+    for si, stage in enumerate(OBS_STAGES):
+        s = np.bincount(row_tbl_arr, stage_sum[:, si], minlength=N)
+        cols[f"stage_{stage}"] = np.where(
+            n_ops_pt > 0, s / np.maximum(n_ops_pt, 1), np.nan)
+
+    # paper-metric throughput (average of per-client rates) and tails,
+    # from the op-order latency column — same expressions as
+    # RecordArray.group_stats / tail_latency
+    thr = np.zeros(N)
+    tails = np.zeros((len(qs), N))
+    for pi, d in enumerate(per):
+        lo, n = d["offset"], d["n"]
+        lat_pt = lat_op[lo:lo + n]
+        t0_pt = flat["t0"][lo:lo + n]
+        end_pt = t0_pt + lat_pt
+        rates = []
+        s = lo
+        for ln in d["seg_len"]:
+            span = (end_pt[s - lo:s - lo + ln].max()
+                    - t0_pt[s - lo:s - lo + ln].min())
+            if span > 0:
+                rates.append(ln / span)
+            s += ln
+        thr[pi] = sum(rates) / len(rates) if rates else 0.0
+        if qs:
+            tails[:, pi] = np.percentile(lat_pt, qs)
+    cols["throughput"] = thr
+    for q, t in zip(qs, tails):
+        cols[f"p{q:g}_latency"] = t
+    return SweepResult(points, cols, walltime() - t_wall,
+                       dict(path="device", device_s=device_s))
+
+
+def _open_build(points: Sequence[SweepPoint], *, duration: float,
+                setting: str, seed: int, service: Optional[ServiceParams],
+                virtual_nodes: int) -> dict:
+    """Host side of the open-loop sweep: schedules, routes and page
+    penalties (seed-exact numpy), laid out as flat op columns plus the
+    row-space index ``gidx`` of shape (R, Ls) with padded ragged tails.
+    """
     svcp = service or ServiceParams()
     dm = _DelayModel(SETTINGS[setting], svcp)
     capacity = max(1, svcp.page_cache_keys)
-    qs = tuple(float(q) for q in percentiles)
-
-    # ---- host side: schedules, routes, penalties (seed-exact numpy) ----
     cols_op: Dict[str, List[np.ndarray]] = {
         k: [] for k in ("t0", "pens", "is_w", "glob", "lf", "remote",
                         "hops", "client")}
@@ -478,7 +566,6 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     gidx = np.full((R, Ls), n_total, np.int32)
     for r, idx in enumerate(row_idx):
         gidx[r, :len(idx)] = idx
-    valid = gidx < n_total
     tbl_pt = {name: np.tile(np.asarray(getattr(dm, name), np.float64),
                             (len(points), 1))
               for name in _PAIRS}
@@ -486,74 +573,14 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     row_tbl_arr = np.asarray(row_tbl)
     tblr = {name: v[row_tbl_arr] for name, v in tbl_pt.items()}
     max_hops = int(flat["hops"].max()) if n_total else 0
+    return dict(tblr=tblr, flat=flat, gidx=gidx, max_hops=max_hops,
+                per=per, row_tbl=row_tbl_arr, n_total=n_total)
 
-    # ---- the single jitted call ----
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    fn = _compiled(max_hops, scan_backend, bool(interpret))
-    with enable_x64():
-        cnt4, sum4, lat_rows, stage_sum = jax.device_get(fn(
-            {k: jnp.asarray(v) for k, v in tblr.items()},
-            {k: jnp.asarray(v) for k, v in flat.items()
-             if k != "client"},
-            jnp.asarray(gidx)))
 
-    # ---- fold rows back into per-point RecordArray-style aggregates ----
-    lat_op = np.empty(n_total)
-    lat_op[gidx[valid]] = np.asarray(lat_rows)[valid]
-    cnt4 = np.asarray(cnt4, np.float64)
-    sum4 = np.asarray(sum4)
-    N = len(points)
-    cnt_pt = np.zeros((N, 4))
-    sum_pt = np.zeros((N, 4))
-    for c in range(4):
-        cnt_pt[:, c] = np.bincount(row_tbl_arr, cnt4[:, c], minlength=N)
-        sum_pt[:, c] = np.bincount(row_tbl_arr, sum4[:, c], minlength=N)
-
-    # categories: (read-local, read-global, update-local, update-global)
-    sel = {"mean_latency": (0, 1, 2, 3), "read_latency": (0, 1),
-           "update_latency": (2, 3), "local_latency": (0, 2),
-           "global_latency": (1, 3), "update_global_latency": (3,)}
-    cols: Dict[str, np.ndarray] = {
-        "ops": np.asarray([d["n"] for d in per], np.int64)}
-    for name, cats in sel.items():
-        c = cnt_pt[:, list(cats)].sum(axis=1)
-        s = sum_pt[:, list(cats)].sum(axis=1)
-        cols[name] = np.where(c > 0, s / np.maximum(c, 1), np.nan)
-
-    # per-point per-stage mean durations (span model, program aggregates)
-    n_ops_pt = cnt_pt.sum(axis=1)
-    stage_sum = np.asarray(stage_sum, np.float64)
-    for si, stage in enumerate(OBS_STAGES):
-        s = np.bincount(row_tbl_arr, stage_sum[:, si], minlength=N)
-        cols[f"stage_{stage}"] = np.where(
-            n_ops_pt > 0, s / np.maximum(n_ops_pt, 1), np.nan)
-
-    # paper-metric throughput (average of per-client rates) and tails,
-    # from the op-order latency column — same expressions as
-    # RecordArray.group_stats / tail_latency
-    thr = np.zeros(N)
-    tails = np.zeros((len(qs), N))
-    for pi, d in enumerate(per):
-        lo, n = d["offset"], d["n"]
-        lat_pt = lat_op[lo:lo + n]
-        t0_pt = flat["t0"][lo:lo + n]
-        end_pt = t0_pt + lat_pt
-        rates = []
-        s = lo
-        for ln in d["seg_len"]:
-            span = (end_pt[s - lo:s - lo + ln].max()
-                    - t0_pt[s - lo:s - lo + ln].min())
-            if span > 0:
-                rates.append(ln / span)
-            s += ln
-        thr[pi] = sum(rates) / len(rates) if rates else 0.0
-        if qs:
-            tails[:, pi] = np.percentile(lat_pt, qs)
-    cols["throughput"] = thr
-    for q, t in zip(qs, tails):
-        cols[f"p{q:g}_latency"] = t
-    return SweepResult(points, cols, walltime() - t_wall)
+def _open_args(ob: dict) -> tuple:
+    """The open program's device arguments from :func:`_open_build`."""
+    return (ob["tblr"], {k: v for k, v in ob["flat"].items()
+                         if k != "client"}, ob["gidx"])
 
 
 # ===================================================== closed-loop sweep
@@ -699,17 +726,48 @@ def _closed_pad(blk: dict, n_max: int, R_max: int, Ls_max: int
 @lru_cache(maxsize=None)
 def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
                      max_rounds: int, seek: float, R: int, Ls: int):
-    """The raw (unjitted) fixed-point program for one device block."""
+    """The raw (unjitted) fixed-point program for one device block.
+
+    With the exact ``seq`` backend every time is the int64 bit pattern of
+    its IEEE double (:mod:`repro.sim.f64bits`), added with integer ops,
+    so queue orders and the convergence test are bit-for-bit the host's
+    on any device; the closed-form backends compute in float64."""
+    if scan_backend == "seq":
+        add = partial(f64bits.add, jnp)
+        dtype, inf, neg_inf = jnp.int64, f64bits.INF, f64bits.NEG_INF
+        seek_v = int(f64bits.to_bits(seek)[0])
+    else:
+        add = operator.add
+        dtype, inf, neg_inf = jnp.float64, jnp.inf, -jnp.inf  # lint: ignore[EDK104] -- every caller traces under enable_x64 (see _run_closed)
+        seek_v = seek
+
+    def depart(grid_a, grid_s):
+        # leader FIFO commit stage, batched over rows.  "seq" reproduces
+        # the engine's exact sequential recurrence (required for the
+        # <=1e-9 differential contract — see run_sweep); the closed-form
+        # backends are ulp-reassociated
+        if scan_backend == "pallas":
+            return maxplus_depart(grid_a, grid_s, backend="pallas",
+                                  block_rows=8, interpret=interpret)
+        if scan_backend == "assoc":
+            return maxplus_depart(grid_a, grid_s, backend="assoc")
+
+        def step(d_prev, x):
+            d = add(jnp.maximum(x[0], d_prev), x[1])
+            return d, d
+        _, dep = jax.lax.scan(step, jnp.full((R,), neg_inf, dtype),
+                              (grid_a.T, grid_s.T))
+        return dep.T
 
     def one_round(comp, flat, aux, pieces=None):
         n = comp.shape[0]
-        t0 = jnp.where(flat["first"], 0.0,
+        t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
                        jnp.take(comp, flat["pred"], mode="clip"))
         cuts = [] if pieces is not None else None
         arr = arrival_chain(jnp, t0, flat["c_req"], flat["f_req"],
                             flat["sg_req"], flat["h_req"], flat["lf"],
                             flat["glob"], flat["hops"], max_hops,
-                            cuts=cuts)
+                            cuts=cuts, add=add)
         # one stable composite sort of the real ops by (row, arrival)
         # recovers every leader queue at once: stability breaks exact
         # arrival ties by flat index = (pid, op) order, the heap
@@ -723,51 +781,42 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
         # ranks per sorted position are static (row sizes don't change)
         seg_ord = jnp.take(aux["seg"], perm)
         rmin = jax.ops.segment_min(aux["rank"], seg_ord, num_segments=n)
-        pens = jnp.where(aux["rank"] > rmin[seg_ord], 0.0, seek)
-        svc_ord = jnp.take(flat["svc_base"], perm) + pens
-        # leader FIFO commit stage: scatter the ordered queues into the
-        # rectangular (R, Ls) grid through the static position -> slot
-        # map (uncovered slots stay +inf/0 and are never gathered back)
-        # and run the batched max-plus departure scan.  "seq" reproduces
-        # the engine's exact sequential float association (required for
-        # the <=1e-9 differential contract — see run_sweep); the
-        # closed-form backends are ulp-reassociated
-        grid_a = jnp.full((R * Ls,), jnp.inf, arr.dtype).at[
+        pens = jnp.where(aux["rank"] > rmin[seg_ord], jnp.zeros((), dtype),
+                         jnp.asarray(seek_v, dtype))
+        svc_ord = add(jnp.take(flat["svc_base"], perm), pens)
+        # scatter the ordered queues into the rectangular (R, Ls) grid
+        # through the static position -> slot map (uncovered slots stay
+        # +inf/0 and are never gathered back), then the departure scan
+        grid_a = jnp.full((R * Ls,), inf, dtype).at[
             aux["dest"]].set(arr_ord, mode="drop").reshape(R, Ls)
-        grid_s = jnp.zeros((R * Ls,), arr.dtype).at[
+        grid_s = jnp.zeros((R * Ls,), dtype).at[
             aux["dest"]].set(svc_ord, mode="drop").reshape(R, Ls)
-        if scan_backend == "pallas":
-            dep_grid = maxplus_depart(grid_a, grid_s, backend="pallas",
-                                      block_rows=8, interpret=interpret)
-        elif scan_backend == "assoc":
-            dep_grid = maxplus_depart(grid_a, grid_s, backend="assoc")
-        else:
-            dep_grid = maxplus_depart(grid_a, grid_s, backend="ref")
+        dep_grid = depart(grid_a, grid_s)
         dep_ord = jnp.take(dep_grid.reshape(-1), aux["dest"],
-                           mode="fill", fill_value=0.0)
-        dep = jnp.zeros((n,), comp.dtype).at[perm].set(dep_ord)
+                           mode="fill", fill_value=0)
+        dep = jnp.zeros((n,), dtype).at[perm].set(dep_ord)
         ccuts = [] if pieces is not None else None
         new = completion_chain(jnp, dep, flat["q_ri"], flat["sg_resp"],
                                flat["g_resp"], flat["f_resp"],
                                flat["c_resp"], flat["lf"], flat["glob"],
-                               flat["remote"], cuts=ccuts)
+                               flat["remote"], cuts=ccuts, add=add)
         if pieces is not None:
             # span-model pieces: service start = max(arrival, previous
             # departure) per queue slot, clamped to the departure (the
             # closed-form scan backends may reassociate by an ulp)
             prev = jnp.concatenate(
-                [jnp.full((R, 1), -jnp.inf, dep_grid.dtype),
-                 dep_grid[:, :-1]], axis=1)
+                [jnp.full((R, 1), neg_inf, dtype), dep_grid[:, :-1]],
+                axis=1)
             start_grid = jnp.minimum(jnp.maximum(grid_a, prev), dep_grid)
             start_ord = jnp.take(start_grid.reshape(-1), aux["dest"],
-                                 mode="fill", fill_value=0.0)
-            start = jnp.zeros((n,), comp.dtype).at[perm].set(start_ord)
+                                 mode="fill", fill_value=0)
+            start = jnp.zeros((n,), dtype).at[perm].set(start_ord)
             pieces.extend([cuts[0], cuts[1], arr, start, dep, ccuts[0]])
         return new
 
     def run(flat, aux):
         n = flat["c_req"].shape[0]
-        comp0 = jnp.full((n,), jnp.inf, jnp.float64)  # lint: ignore[EDK104] -- every caller traces under enable_x64 (see _run_closed)
+        comp0 = jnp.full((n,), inf, dtype)
 
         def cond(carry):
             _, done, r = carry
@@ -780,7 +829,7 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
 
         comp, done, rounds = jax.lax.while_loop(
             cond, body, (comp0, jnp.asarray(False), jnp.asarray(0)))
-        t0 = jnp.where(flat["first"], 0.0,
+        t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
                        jnp.take(comp, flat["pred"], mode="clip"))
         # one idempotent replay of the converged round keeps the span
         # pieces (b_request, b_route, arrival, start, departure,
@@ -795,19 +844,24 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
 @lru_cache(maxsize=None)
 def _closed_exe(max_hops: int, scan_backend: str, interpret: bool,
                 max_rounds: int, seek: float, R: int, Ls: int,
-                devices: int, impl: str):
-    """Cached executable wrappers (jit / shard_map / pmap) around the
-    round program — cached so repeat sweeps reuse the compiled program.
+                devices: int):
+    """Cached executable wrappers (jit, or jit of a point-sharded
+    ``jax.shard_map`` for ``devices`` > 1) around the round program —
+    cached so repeat sweeps reuse the compiled program.
     """
     run = _closed_round_fn(max_hops, scan_backend, interpret, max_rounds,
                            seek, R, Ls)
-    if impl == "jit":
+    if devices == 1:
         return jax.jit(run)
-    if impl == "pmap":
-        return jax.pmap(run)
+    return _shard_points(run, jax.devices()[:devices])
+
+
+def _shard_points(run, devs: Sequence) -> object:
+    """jit of ``run`` sharded over a leading point-block axis, one block
+    per device of ``devs``."""
     from jax.sharding import Mesh, PartitionSpec
 
-    mesh = Mesh(np.asarray(jax.devices()[:devices]), ("pt",))
+    mesh = Mesh(np.asarray(devs), ("pt",))
     spec = PartitionSpec("pt")
 
     def shard_fn(flat, aux):
@@ -816,13 +870,13 @@ def _closed_exe(max_hops: int, scan_backend: str, interpret: bool,
             {k: v[0] for k, v in aux.items()})
         return comp[None], t0[None], done[None], r[None], pieces[None]
 
-    # check_rep off: each shard runs its own data-dependent while_loop
+    # check_vma off: each shard runs its own data-dependent while_loop
     # trip count (idempotent past its fixed point, so shards that
     # converge early stay bit-identical to the single-device program)
-    return jax.jit(shard_map(shard_fn, mesh=mesh,
-                             in_specs=(spec, spec),
-                             out_specs=(spec,) * 5,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                                 in_specs=(spec, spec),
+                                 out_specs=(spec,) * 5,
+                                 check_vma=False))
 
 
 def _closed_rounds_host(built: Sequence[dict], capacity: int, seek: float,
@@ -895,7 +949,7 @@ def _closed_rounds_host(built: Sequence[dict], capacity: int, seek: float,
 
 def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
                 service: Optional[ServiceParams], virtual_nodes: int,
-                scan_backend: str, interpret: Optional[bool],
+                scan_backend: str, interpret: bool,
                 percentiles: Sequence[float], devices: int,
                 max_rounds: Optional[int]) -> SweepResult:
     t_wall = walltime()
@@ -907,8 +961,6 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
     dm = _DelayModel(SETTINGS[setting], svcp)
     capacity = max(1, svcp.page_cache_keys)
     qs = tuple(float(q) for q in percentiles)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     built = [_closed_point_build(p, seed, dm, capacity, virtual_nodes)
              for p in points]
@@ -920,37 +972,19 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
     seek = float(dm.seek)
     args = (max_hops, scan_backend, bool(interpret), int(max_rounds),
             seek)
+    if devices > jax.local_device_count():
+        raise ValueError(
+            f"devices={devices} but only {jax.local_device_count()} "
+            "jax devices visible (on CPU set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=N before "
+            "importing jax)")
 
     if any(b["evict"] for b in built):
         comp_pt, t0_pt, pieces_pt = _closed_rounds_host(
             built, capacity, seek, max_hops, max_rounds)
-    elif devices == 1:
-        blk = _closed_assemble(built)
-        R = len(blk["rows"])
-        Ls = max(len(m) for m in blk["rows"])
-        flat, aux = _closed_pad(blk, blk["n"], R, Ls)
-        with enable_x64():
-            comp, t0f, done, _, pieces = jax.device_get(_closed_exe(
-                *args, R, Ls, 1, "jit")(
-                {k: jnp.asarray(v) for k, v in flat.items()},
-                {k: jnp.asarray(v) for k, v in aux.items()}))
-        if not bool(done):
-            raise RuntimeError(
-                f"closed-loop sweep did not converge in {max_rounds} "
-                "rounds; raise max_rounds")
-        comp_pt, t0_pt, pieces_pt, off = [], [], [], 0
-        for b in built:
-            comp_pt.append(comp[off:off + b["n"]])
-            t0_pt.append(t0f[off:off + b["n"]])
-            pieces_pt.append(pieces[:, off:off + b["n"]])
-            off += b["n"]
+        info: Dict[str, object] = dict(path="host")
     else:
-        if devices > jax.local_device_count():
-            raise ValueError(
-                f"devices={devices} but only {jax.local_device_count()} "
-                "jax devices visible (on CPU set XLA_FLAGS="
-                "--xla_force_host_platform_device_count=N before "
-                "importing jax)")
+        # one device block per shard; a single block runs unsharded
         D = min(devices, len(points))
         dev_pts = [[pi for pi in range(len(points)) if pi % D == d]
                    for d in range(D)]
@@ -960,22 +994,31 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
         R_max = max(len(b["rows"]) for b in blks)
         Ls_max = max(max(len(m) for m in b["rows"]) for b in blks)
         padded = [_closed_pad(b, n_max, R_max, Ls_max) for b in blks]
-        flat_s = {k: np.stack([f[k] for f, _ in padded])
-                  for k in padded[0][0]}
-        aux_s = {k: np.stack([a[k] for _, a in padded])
-                 for k in padded[0][1]}
-        with enable_x64():
-            flat_j = {k: jnp.asarray(v) for k, v in flat_s.items()}
-            aux_j = {k: jnp.asarray(v) for k, v in aux_s.items()}
-            sh = (*args, R_max, Ls_max)
-            if shard_map is None:
-                out = _closed_exe(*sh, D, "pmap")(flat_j, aux_j)
-            else:
-                try:
-                    out = _closed_exe(*sh, D, "shard")(flat_j, aux_j)
-                except Exception:  # pragma: no cover - jax-version paths
-                    out = _closed_exe(*sh, D, "pmap")(flat_j, aux_j)
-            comp_s, t0_s, done_s, _, pieces_s = jax.device_get(out)
+        exact = scan_backend == "seq"
+        if exact:   # times travel as IEEE bit patterns (f64bits)
+            padded = [({k: f64bits.to_bits(v) if v.dtype == np.float64
+                        else v for k, v in f.items()}, a)
+                      for f, a in padded]
+        if D == 1:
+            flat, aux = padded[0]
+        else:   # one leading row per device block, stacked on the host
+            flat = {k: np.stack([f[k] for f, _ in padded])
+                    for k in padded[0][0]}
+            aux = {k: np.stack([a[k] for _, a in padded])
+                   for k in padded[0][1]}
+        with jax.enable_x64(True):
+            exe = _closed_exe(*args, R_max, Ls_max, D)
+            t_dev = walltime()
+            out = jax.device_get(exe(
+                {k: jnp.asarray(v) for k, v in flat.items()},
+                {k: jnp.asarray(v) for k, v in aux.items()}))
+            device_s = walltime() - t_dev
+        if D == 1:
+            out = [np.asarray(o)[None] for o in out]
+        comp_s, t0_s, done_s, rounds_s, pieces_s = out
+        if exact:
+            comp_s, t0_s, pieces_s = (f64bits.from_bits(v) for v in
+                                      (comp_s, t0_s, pieces_s))
         if not bool(np.all(done_s)):
             raise RuntimeError(
                 f"closed-loop sweep did not converge in {max_rounds} "
@@ -991,6 +1034,8 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
                 t0_pt[pi] = t0_s[d, off:off + n]
                 pieces_pt[pi] = pieces_s[d, :, off:off + n]
                 off += n
+        info = dict(path="device", devices=D,
+                    rounds=int(np.max(rounds_s)), device_s=device_s)
 
     # ---- fold into per-point RecordArray-style aggregates ----
     N = len(points)
@@ -1049,4 +1094,4 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
             tails[:, pi] = np.percentile(lat, qs)
     for q, t in zip(qs, tails):
         cols[f"p{q:g}_latency"] = t
-    return SweepResult(points, cols, walltime() - t_wall)
+    return SweepResult(points, cols, walltime() - t_wall, info)

@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import bisect
 import heapq
+import operator
 from typing import Dict, Generator, List, Optional, Tuple
 
 import numpy as np
@@ -928,7 +929,8 @@ def run_closed_loop_fast(sim: SimEdgeKV, plan: List[ThreadPlan]) -> None:
 
 # --------------------------------------------------- pure delay columns
 def arrival_chain(xp, t0, c_req, f_req, sg_req, h_req, lf, glob, hops,
-                  max_hops: int, cuts: Optional[list] = None):
+                  max_hops: int, cuts: Optional[list] = None,
+                  add=operator.add):
     """Leader-arrival times from per-op delay-component columns.
 
     Masked sequential adds in the oracle's Timeout term order (float
@@ -942,37 +944,40 @@ def arrival_chain(xp, t0, c_req, f_req, sg_req, h_req, lf, glob, hops,
     admit), ``b_route`` (after the overlay hops), ``b_ingress`` (after
     gw -> leader) — intermediate values of the SAME adds, so traced runs
     cost nothing extra and cannot drift from the untraced clock.
+
+    ``add`` replaces float ``+`` (the sweep's exact closed loop passes
+    :func:`repro.sim.f64bits.add` over int64 bit patterns).
     """
-    arr = t0 + c_req
-    arr = xp.where(lf, arr + f_req, arr)
-    arr = xp.where(glob, arr + sg_req, arr)
+    arr = add(t0, c_req)
+    arr = xp.where(lf, add(arr, f_req), arr)
+    arr = xp.where(glob, add(arr, sg_req), arr)
     if cuts is not None:
         cuts.append(arr)                 # b_request
     for k in range(max_hops):
-        arr = xp.where(hops > k, arr + h_req, arr)
+        arr = xp.where(hops > k, add(arr, h_req), arr)
     if cuts is not None:
         cuts.append(arr)                 # b_route
-    arr = xp.where(glob, arr + sg_req, arr)
+    arr = xp.where(glob, add(arr, sg_req), arr)
     if cuts is not None:
         cuts.append(arr)                 # b_ingress
     return arr
 
 
 def completion_chain(xp, dep, q_or_ri, sg_resp, g_resp, f_resp, c_resp,
-                     lf, glob, remote, cuts: Optional[list] = None):
+                     lf, glob, remote, cuts: Optional[list] = None,
+                     add=operator.add):
     """Completion times from leader departures: quorum/ReadIndex round,
     then the response hop chain (same masked-sequential-add contract as
-    :func:`arrival_chain`).  ``cuts`` collects ``b_replicate`` (after the
-    quorum/ReadIndex round) for tracing."""
-    comp = dep + q_or_ri
+    :func:`arrival_chain`, ``add`` included).  ``cuts`` collects
+    ``b_replicate`` (after the quorum/ReadIndex round) for tracing."""
+    comp = add(dep, q_or_ri)
     if cuts is not None:
         cuts.append(comp)                # b_replicate
-    comp = xp.where(glob, comp + sg_resp, comp)
-    comp = xp.where(remote, comp + g_resp, comp)
-    comp = xp.where(glob, comp + sg_resp, comp)
-    comp = xp.where(lf, comp + f_resp, comp)
-    comp = comp + c_resp
-    return comp
+    comp = xp.where(glob, add(comp, sg_resp), comp)
+    comp = xp.where(remote, add(comp, g_resp), comp)
+    comp = xp.where(glob, add(comp, sg_resp), comp)
+    comp = xp.where(lf, add(comp, f_resp), comp)
+    return add(comp, c_resp)
 
 
 # ----------------------------------------------------- open-loop pieces

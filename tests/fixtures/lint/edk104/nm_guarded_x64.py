@@ -1,8 +1,8 @@
-"""Near miss: the same request under jax.experimental.enable_x64."""
+"""Near miss: the same request under jax.enable_x64."""
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 
 def widen(x):
-    with enable_x64():
+    with jax.enable_x64(True):
         return jnp.asarray(x, dtype=jnp.float64)

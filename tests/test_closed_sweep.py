@@ -82,7 +82,8 @@ def test_closed_sweep_pallas_backend_matches_assoc():
     this doubles as an order-stability check."""
     pts = closed_grid(threads=4, ops=32)[:4]
     a = run_sweep(pts, loop="closed", seed=0, scan_backend="assoc")
-    b = run_sweep(pts, loop="closed", seed=0, scan_backend="pallas")
+    b = run_sweep(pts, loop="closed", seed=0, scan_backend="pallas",
+                  interpret=True)
     for k in a.columns:
         np.testing.assert_allclose(a.columns[k], b.columns[k],
                                    rtol=1e-9)

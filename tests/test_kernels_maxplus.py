@@ -5,7 +5,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental import enable_x64
 
 from repro.kernels.maxplus_scan import (maxplus_depart,
                                         maxplus_depart_kernel,
@@ -53,7 +52,7 @@ def test_numpy_backend_is_bit_exact_vs_inline_oracle(L):
 @pytest.mark.parametrize("L,chunk", [(8, 8), (96, 16), (250, 64)])
 def test_jax_backends_match_numpy_oracle_f64(backend, L, chunk):
     a, s = make((4, L), seed=L)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(maxplus_depart(jnp.asarray(a), jnp.asarray(s),
                                         backend=backend, chunk=chunk,
                                         interpret=True))
@@ -87,7 +86,7 @@ def test_segment_resets(backend):
     reset[1, 0] = True
     reset[2, 39] = True
     want = sequential_oracle(a, s, reset=reset)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(maxplus_depart(
             jnp.asarray(a) if backend != "numpy" else a,
             jnp.asarray(s) if backend != "numpy" else s,
@@ -101,7 +100,7 @@ def test_init_busy_leader(backend):
     a, s = make((4, 25), seed=3)
     init = np.array([0.0, 5.0, 20.0, 2.5])
     want = sequential_oracle(a, s, init=init)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(maxplus_depart(
             jnp.asarray(a) if backend != "numpy" else a,
             jnp.asarray(s) if backend != "numpy" else s,
@@ -114,7 +113,7 @@ def test_pallas_rows_are_independent():
     """The VMEM carry must reset per row: permuting rows permutes
     departures."""
     a, s = make((5, 64), seed=11)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = np.asarray(maxplus_depart(jnp.asarray(a), jnp.asarray(s),
                                         backend="pallas", chunk=16,
                                         interpret=True))
@@ -129,7 +128,7 @@ def test_pallas_rows_are_independent():
 def test_pallas_pad_to_chunk():
     """Non-multiple lengths are padded and sliced back."""
     a, s = make((2, 37), seed=13)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(maxplus_depart(jnp.asarray(a), jnp.asarray(s),
                                         backend="pallas", chunk=16,
                                         interpret=True))
@@ -150,7 +149,7 @@ def test_pallas_batched_rows_matches_oracle(block_rows, R, L):
     """The ``block_rows`` grid axis tiles rows; results must not depend
     on the tile size, including when R is not a multiple of it."""
     a, s = make((R, L), seed=R * 100 + L)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(maxplus_depart(jnp.asarray(a), jnp.asarray(s),
                                         backend="pallas", chunk=16,
                                         block_rows=block_rows,
@@ -163,7 +162,7 @@ def test_pallas_block_rows_bitwise_vs_block_rows_one():
     """Row tiling is pure batching: each row's scan is independent, so
     block_rows must be bit-invisible, not just within tolerance."""
     a, s = make((7, 48), seed=41)
-    with enable_x64():
+    with jax.enable_x64(True):
         one = np.asarray(maxplus_depart(jnp.asarray(a), jnp.asarray(s),
                                         backend="pallas", chunk=16,
                                         block_rows=1, interpret=True))
@@ -184,6 +183,6 @@ def test_monotone_departures_and_fifo_invariant():
 
 def test_ref_rejects_nothing_on_1d():
     a, s = make((16,), seed=29)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(maxplus_depart_ref(a, s))
     np.testing.assert_allclose(got, numpy_oracle(a, s), rtol=1e-12)

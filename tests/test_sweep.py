@@ -68,9 +68,21 @@ def test_run_sweep_cloud_setting_and_seed():
 def test_run_sweep_pallas_scan_backend():
     pts = [SweepPoint(p_global=0.5, rate=120.0, groups=3)]
     a = run_sweep(pts, duration=1.0)
-    b = run_sweep(pts, duration=1.0, scan_backend="pallas")
+    b = run_sweep(pts, duration=1.0, scan_backend="pallas",
+                  interpret=True)
     for k in a.columns:
         np.testing.assert_allclose(a.columns[k], b.columns[k], rtol=1e-12)
+
+
+@pytest.mark.parametrize("loop", ["open", "closed"])
+def test_pallas_scan_backend_refuses_compiled_mode(loop):
+    """The kernel does not compile for the TPU at the sweep's float64, so
+    without interpret=True the sweep refuses before tracing instead of
+    degrading into another backend."""
+    pts = [SweepPoint(p_global=0.5, rate=120.0, groups=3, threads=4,
+                      ops=16)]
+    with pytest.raises(ValueError, match="interpret=True"):
+        run_sweep(pts, duration=1.0, loop=loop, scan_backend="pallas")
 
 
 def test_run_sweep_deterministic_and_seed_sensitive():
