@@ -1,0 +1,11 @@
+"""Host seconds of ``run_sweep``'s fold (the program's span
+``run_sweep.fold``: bit patterns back to doubles, per-point columns),
+mean over the window's sweeps.  Nothing where a sweep reports no such
+span."""
+from statistics import fmean
+
+
+def read(run):
+    name = "run_sweep.fold"
+    v = [(s.get("spans") or {}).get(name) for s in run["sweeps"]]
+    return fmean(v) if v and None not in v else None

@@ -1,0 +1,10 @@
+"""Device seconds per sweep of the closed round's scatter into the scan
+grid (named scope ``closed.to_grid``: the two scatters of arrivals and
+service times into the (rows, longest queue) grid), its rounds and its
+share of the replay together.  Nothing where the trace has no such
+scope."""
+import scope_reduce
+
+
+def read(run):
+    return scope_reduce.per_sweep(run, "closed.to_grid")

@@ -10,21 +10,20 @@ Three pillars (see README "Observability"):
 * **Metrics registry** (:mod:`repro.obs.metrics`): typed
   Counter/Gauge/Histogram instruments behind stable dotted names,
   snapshot/diff-able, near-zero overhead when disabled.
-* **Profiling** (:mod:`repro.obs.profile`, :func:`walltime`): the one
-  sanctioned wall-clock, plus compile-time / trace-count /
-  device-memory wrappers for the jitted kernels.
+* **Host timing** (:func:`walltime`, :func:`span`): the one sanctioned
+  wall-clock, and named host spans that land both in a
+  ``jax.profiler`` trace and in the seconds a sweep reports.
 
 CLI: ``python -m repro.obs {summarize,diff,flamegraph} trace.json``.
 """
 from .clock import timed, walltime
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       NULL_INSTRUMENT, format_snapshot)
-from .profile import TraceCounter, profile_compile, profile_maxplus
+from .spans import span
 from .trace import BOUNDARY_FIELDS, STAGES, TraceSet
 
 __all__ = [
     "BOUNDARY_FIELDS", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "NULL_INSTRUMENT", "STAGES", "TraceCounter", "TraceSet",
-    "format_snapshot", "profile_compile", "profile_maxplus", "timed",
-    "walltime",
+    "NULL_INSTRUMENT", "STAGES", "TraceSet", "format_snapshot", "span",
+    "timed", "walltime",
 ]

@@ -75,7 +75,7 @@ import jax.numpy as jnp
 
 from repro.core.hashring import ChordRing, stable_hash
 from repro.kernels.maxplus_scan import maxplus_depart
-from repro.obs import walltime
+from repro.obs import span, walltime
 from repro.obs.trace import STAGES as OBS_STAGES
 
 from . import f64bits
@@ -144,15 +144,40 @@ def closed_grid(p_globals: Sequence[float] = (0.0, 0.25, 0.5, 1.0),
 class SweepResult:
     """Batched sweep aggregates — one SoA column per metric, one slot per
     grid point (the :class:`~repro.sim.records.RecordArray` aggregate
-    shape, lifted to a whole grid)."""
+    shape, lifted to a whole grid).
+
+    ``walltime_s`` is the host's wall time of the whole ``run_sweep``
+    call.  ``info`` says how the grid ran:
+
+    * ``path`` — ``"device"``, or ``"host"`` for the closed loop's
+      eviction regime, whose fixed point runs in numpy;
+    * ``spans`` — wall seconds of ``run_sweep``'s named host steps
+      (:func:`repro.obs.span`; the same names appear in a
+      ``jax.profiler`` trace): ``run_sweep.build`` (schedules, routes,
+      padding, bit patterns), ``run_sweep.dispatch`` (transfers and the
+      enqueued call; trace and compile on a cold call),
+      ``run_sweep.wait`` (``device_get``), ``run_sweep.fold`` (per-point
+      columns), and on the host path ``run_sweep.host_rounds`` in place
+      of dispatch and wait;
+    * ``device_s`` (device path) — wall seconds from the first transfer
+      through the final ``device_get``, so at least dispatch + wait;
+
+    and on the closed loop's device path:
+
+    * ``devices`` — point blocks, one per device;
+    * ``rounds`` — fixed-point rounds, the most of any block;
+    * ``changed`` — op updates that changed a completion, summed over
+      every round and block (real ops only: pad ops are not counted);
+    * ``op_rounds`` — each block's rounds times its real ops, summed,
+      so ``changed / op_rounds`` is the share of round work that moved
+      the fixed point;
+    * ``grid_slots`` — blocks x rows x longest queue, the slots of the
+      departure scan's grid, of which the real ops fill
+      ``columns["ops"].sum()``.
+    """
     points: List[SweepPoint]
     columns: Dict[str, np.ndarray]
     walltime_s: float = 0.0
-    # how the grid ran: ``path`` ("device", or "host" for the eviction
-    # regime's host fixed point), ``device_s`` (walltime from the
-    # host->device transfer through the final ``device_get``, compile
-    # included on a cold call), and for the closed loop ``devices`` and
-    # the fixed point's ``rounds`` (max over shards)
     info: Dict[str, object] = field(default_factory=dict)
 
     def __len__(self) -> int:
@@ -408,22 +433,35 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     if devices != 1:
         raise ValueError("devices > 1 requires loop='closed'")
     t_wall = walltime()
+    spans: Dict[str, float] = {}
     qs = tuple(float(q) for q in percentiles)
-    ob = _open_build(points, duration=duration, setting=setting, seed=seed,
-                     service=service, virtual_nodes=virtual_nodes)
-    per, flat, gidx = ob["per"], ob["flat"], ob["gidx"]
-    n_total, row_tbl_arr = ob["n_total"], ob["row_tbl"]
-    valid = gidx < n_total
+    with span("run_sweep.build", spans):
+        ob = _open_build(points, duration=duration, setting=setting,
+                         seed=seed, service=service,
+                         virtual_nodes=virtual_nodes)
 
     # ---- the single jitted call ----
     fn = _compiled(ob["max_hops"], scan_backend, bool(interpret))
     with jax.enable_x64(True):
         t_dev = walltime()
-        cnt4, sum4, lat_rows, stage_sum = jax.device_get(fn(
-            *jax.tree.map(jnp.asarray, _open_args(ob))))
+        with span("run_sweep.dispatch", spans):
+            out = fn(*jax.tree.map(jnp.asarray, _open_args(ob)))
+        with span("run_sweep.wait", spans):
+            cnt4, sum4, lat_rows, stage_sum = jax.device_get(out)
         device_s = walltime() - t_dev
+    with span("run_sweep.fold", spans):
+        cols = _open_fold(points, ob, qs, cnt4, sum4, lat_rows, stage_sum)
+    return SweepResult(points, cols, walltime() - t_wall,
+                       dict(path="device", device_s=device_s, spans=spans))
 
-    # ---- fold rows back into per-point RecordArray-style aggregates ----
+
+def _open_fold(points: Sequence[SweepPoint], ob: dict, qs: Tuple[float, ...],
+               cnt4, sum4, lat_rows, stage_sum) -> Dict[str, np.ndarray]:
+    """Fold the open program's rows back into per-point
+    RecordArray-style aggregate columns."""
+    per, flat, gidx = ob["per"], ob["flat"], ob["gidx"]
+    n_total, row_tbl_arr = ob["n_total"], ob["row_tbl"]
+    valid = gidx < n_total
     lat_op = np.empty(n_total)
     lat_op[gidx[valid]] = np.asarray(lat_rows)[valid]
     cnt4 = np.asarray(cnt4, np.float64)
@@ -478,8 +516,7 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     cols["throughput"] = thr
     for q, t in zip(qs, tails):
         cols[f"p{q:g}_latency"] = t
-    return SweepResult(points, cols, walltime() - t_wall,
-                       dict(path="device", device_s=device_s))
+    return cols
 
 
 def _open_build(points: Sequence[SweepPoint], *, duration: float,
@@ -759,84 +796,108 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
                               (grid_a.T, grid_s.T))
         return dep.T
 
+    # each stage of the round runs under a named scope, so that a device
+    # trace attributes every op to its stage by the name in its
+    # ``tf_op`` metadata (``.../closed.to_grid/scatter``)
     def one_round(comp, flat, aux, pieces=None):
         n = comp.shape[0]
-        t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
-                       jnp.take(comp, flat["pred"], mode="clip"))
-        cuts = [] if pieces is not None else None
-        arr = arrival_chain(jnp, t0, flat["c_req"], flat["f_req"],
-                            flat["sg_req"], flat["h_req"], flat["lf"],
-                            flat["glob"], flat["hops"], max_hops,
-                            cuts=cuts, add=add)
+        with jax.named_scope("closed.arrival"):
+            t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
+                           jnp.take(comp, flat["pred"], mode="clip"))
+            cuts = [] if pieces is not None else None
+            arr = arrival_chain(jnp, t0, flat["c_req"], flat["f_req"],
+                                flat["sg_req"], flat["h_req"], flat["lf"],
+                                flat["glob"], flat["hops"], max_hops,
+                                cuts=cuts, add=add)
         # one stable composite sort of the real ops by (row, arrival)
         # recovers every leader queue at once: stability breaks exact
         # arrival ties by flat index = (pid, op) order, the heap
         # engine's tie-break, and pad ops sort after every real row
-        _, arr_ord, perm = jax.lax.sort(
-            (aux["row"], arr, jnp.arange(n, dtype=jnp.int32)),
-            num_keys=2, is_stable=True)
+        with jax.named_scope("closed.order"):
+            _, arr_ord, perm = jax.lax.sort(
+                (aux["row"], arr, jnp.arange(n, dtype=jnp.int32)),
+                num_keys=2, is_stable=True)
         # seen-before page penalties (the no-eviction LRU regime): an op
         # hits iff a same-key op sits earlier in its queue, i.e. its
         # rank exceeds the min rank of its static (row, key) segment;
         # ranks per sorted position are static (row sizes don't change)
-        seg_ord = jnp.take(aux["seg"], perm)
-        rmin = jax.ops.segment_min(aux["rank"], seg_ord, num_segments=n)
-        pens = jnp.where(aux["rank"] > rmin[seg_ord], jnp.zeros((), dtype),
-                         jnp.asarray(seek_v, dtype))
-        svc_ord = add(jnp.take(flat["svc_base"], perm), pens)
+        with jax.named_scope("closed.lru"):
+            seg_ord = jnp.take(aux["seg"], perm)
+            rmin = jax.ops.segment_min(aux["rank"], seg_ord,
+                                       num_segments=n)
+            pens = jnp.where(aux["rank"] > rmin[seg_ord],
+                             jnp.zeros((), dtype),
+                             jnp.asarray(seek_v, dtype))
+            svc_ord = add(jnp.take(flat["svc_base"], perm), pens)
         # scatter the ordered queues into the rectangular (R, Ls) grid
         # through the static position -> slot map (uncovered slots stay
         # +inf/0 and are never gathered back), then the departure scan
-        grid_a = jnp.full((R * Ls,), inf, dtype).at[
-            aux["dest"]].set(arr_ord, mode="drop").reshape(R, Ls)
-        grid_s = jnp.zeros((R * Ls,), dtype).at[
-            aux["dest"]].set(svc_ord, mode="drop").reshape(R, Ls)
-        dep_grid = depart(grid_a, grid_s)
-        dep_ord = jnp.take(dep_grid.reshape(-1), aux["dest"],
-                           mode="fill", fill_value=0)
-        dep = jnp.zeros((n,), dtype).at[perm].set(dep_ord)
-        ccuts = [] if pieces is not None else None
-        new = completion_chain(jnp, dep, flat["q_ri"], flat["sg_resp"],
-                               flat["g_resp"], flat["f_resp"],
-                               flat["c_resp"], flat["lf"], flat["glob"],
-                               flat["remote"], cuts=ccuts, add=add)
+        with jax.named_scope("closed.to_grid"):
+            grid_a = jnp.full((R * Ls,), inf, dtype).at[
+                aux["dest"]].set(arr_ord, mode="drop").reshape(R, Ls)
+            grid_s = jnp.zeros((R * Ls,), dtype).at[
+                aux["dest"]].set(svc_ord, mode="drop").reshape(R, Ls)
+        with jax.named_scope("closed.depart"):
+            dep_grid = depart(grid_a, grid_s)
+        with jax.named_scope("closed.from_grid"):
+            dep_ord = jnp.take(dep_grid.reshape(-1), aux["dest"],
+                               mode="fill", fill_value=0)
+            dep = jnp.zeros((n,), dtype).at[perm].set(dep_ord)
+        with jax.named_scope("closed.completion"):
+            ccuts = [] if pieces is not None else None
+            new = completion_chain(jnp, dep, flat["q_ri"], flat["sg_resp"],
+                                   flat["g_resp"], flat["f_resp"],
+                                   flat["c_resp"], flat["lf"], flat["glob"],
+                                   flat["remote"], cuts=ccuts, add=add)
         if pieces is not None:
             # span-model pieces: service start = max(arrival, previous
             # departure) per queue slot, clamped to the departure (the
             # closed-form scan backends may reassociate by an ulp)
-            prev = jnp.concatenate(
-                [jnp.full((R, 1), neg_inf, dtype), dep_grid[:, :-1]],
-                axis=1)
-            start_grid = jnp.minimum(jnp.maximum(grid_a, prev), dep_grid)
-            start_ord = jnp.take(start_grid.reshape(-1), aux["dest"],
-                                 mode="fill", fill_value=0)
-            start = jnp.zeros((n,), dtype).at[perm].set(start_ord)
+            with jax.named_scope("closed.from_grid"):
+                prev = jnp.concatenate(
+                    [jnp.full((R, 1), neg_inf, dtype), dep_grid[:, :-1]],
+                    axis=1)
+                start_grid = jnp.minimum(jnp.maximum(grid_a, prev),
+                                         dep_grid)
+                start_ord = jnp.take(start_grid.reshape(-1), aux["dest"],
+                                     mode="fill", fill_value=0)
+                start = jnp.zeros((n,), dtype).at[perm].set(start_ord)
             pieces.extend([cuts[0], cuts[1], arr, start, dep, ccuts[0]])
         return new
 
     def run(flat, aux):
         n = flat["c_req"].shape[0]
         comp0 = jnp.full((n,), inf, dtype)
+        real = aux["row"] < R      # pad ops sit in the one-past-end row
 
         def cond(carry):
             _, done, r = carry
             return jnp.logical_and(jnp.logical_not(done), r < max_rounds)
 
+        # the state is the completions and the count of real ops whose
+        # completion changed, summed over the rounds so far
         def body(carry):
-            comp, _, r = carry
+            (comp, changed), _, r = carry
             new = one_round(comp, flat, aux)
-            return new, jnp.all(new == comp), r + 1
+            with jax.named_scope("closed.converge"):
+                diff = new != comp
+                changed = changed + jnp.sum(diff & real, dtype=jnp.int32)
+                return (new, changed), ~jnp.any(diff), r + 1
 
-        comp, done, rounds = jax.lax.while_loop(
-            cond, body, (comp0, jnp.asarray(False), jnp.asarray(0)))
-        t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
-                       jnp.take(comp, flat["pred"], mode="clip"))
+        (comp, changed), done, rounds = jax.lax.while_loop(
+            cond, body, ((comp0, jnp.zeros((), jnp.int64)),
+                         jnp.asarray(False), jnp.asarray(0)))
         # one idempotent replay of the converged round keeps the span
         # pieces (b_request, b_route, arrival, start, departure,
         # b_replicate) as extra device outputs — no host callbacks
-        pieces: list = []
-        one_round(comp, flat, aux, pieces=pieces)
-        return comp, t0, done, rounds, jnp.stack(pieces)
+        with jax.named_scope("closed.replay"):
+            with jax.named_scope("closed.arrival"):
+                t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
+                               jnp.take(comp, flat["pred"], mode="clip"))
+            pieces: list = []
+            one_round(comp, flat, aux, pieces=pieces)
+            pieces = jnp.stack(pieces)
+        return comp, t0, done, jnp.stack([rounds, changed]), pieces
 
     return run
 
@@ -865,10 +926,9 @@ def _shard_points(run, devs: Sequence) -> object:
     spec = PartitionSpec("pt")
 
     def shard_fn(flat, aux):
-        comp, t0, done, r, pieces = run(
-            {k: v[0] for k, v in flat.items()},
-            {k: v[0] for k, v in aux.items()})
-        return comp[None], t0[None], done[None], r[None], pieces[None]
+        out = run({k: v[0] for k, v in flat.items()},
+                  {k: v[0] for k, v in aux.items()})
+        return tuple(o[None] for o in out)
 
     # check_vma off: each shard runs its own data-dependent while_loop
     # trip count (idempotent past its fixed point, so shards that
@@ -953,6 +1013,7 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
                 percentiles: Sequence[float], devices: int,
                 max_rounds: Optional[int]) -> SweepResult:
     t_wall = walltime()
+    spans: Dict[str, float] = {}
     for p in points:
         if p.threads < 1 or p.ops < 1:
             raise ValueError(
@@ -962,8 +1023,9 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
     capacity = max(1, svcp.page_cache_keys)
     qs = tuple(float(q) for q in percentiles)
 
-    built = [_closed_point_build(p, seed, dm, capacity, virtual_nodes)
-             for p in points]
+    with span("run_sweep.build", spans):
+        built = [_closed_point_build(p, seed, dm, capacity, virtual_nodes)
+                 for p in points]
     max_hops = max(b["max_hops"] for b in built)
     if max_rounds is None:
         # the resolved wavefront advances >= 1 op per thread per round;
@@ -980,10 +1042,16 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
             "importing jax)")
 
     if any(b["evict"] for b in built):
-        comp_pt, t0_pt, pieces_pt = _closed_rounds_host(
-            built, capacity, seek, max_hops, max_rounds)
-        info: Dict[str, object] = dict(path="host")
-    else:
+        with span("run_sweep.host_rounds", spans):
+            comp_pt, t0_pt, pieces_pt = _closed_rounds_host(
+                built, capacity, seek, max_hops, max_rounds)
+        with span("run_sweep.fold", spans):
+            cols = _closed_fold(points, built, qs, comp_pt, t0_pt,
+                                pieces_pt)
+        return SweepResult(points, cols, walltime() - t_wall,
+                           dict(path="host", spans=spans))
+
+    with span("run_sweep.build", spans):
         # one device block per shard; a single block runs unsharded
         D = min(devices, len(points))
         dev_pts = [[pi for pi in range(len(points)) if pi % D == d]
@@ -1006,16 +1074,22 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
                     for k in padded[0][0]}
             aux = {k: np.stack([a[k] for _, a in padded])
                    for k in padded[0][1]}
-        with jax.enable_x64(True):
-            exe = _closed_exe(*args, R_max, Ls_max, D)
-            t_dev = walltime()
-            out = jax.device_get(exe(
-                {k: jnp.asarray(v) for k, v in flat.items()},
-                {k: jnp.asarray(v) for k, v in aux.items()}))
-            device_s = walltime() - t_dev
+    with jax.enable_x64(True):
+        exe = _closed_exe(*args, R_max, Ls_max, D)
+        t_dev = walltime()
+        # dispatch holds the transfers and the enqueue, and on a cold
+        # call the trace and compile too; wait is the device's remainder
+        with span("run_sweep.dispatch", spans):
+            out = exe({k: jnp.asarray(v) for k, v in flat.items()},
+                      {k: jnp.asarray(v) for k, v in aux.items()})
+        with span("run_sweep.wait", spans):
+            out = jax.device_get(out)
+        device_s = walltime() - t_dev
+    with span("run_sweep.fold", spans):
         if D == 1:
             out = [np.asarray(o)[None] for o in out]
-        comp_s, t0_s, done_s, rounds_s, pieces_s = out
+        comp_s, t0_s, done_s, counts_s, pieces_s = out
+        rounds_s, changed_s = counts_s[:, 0], counts_s[:, 1]
         if exact:
             comp_s, t0_s, pieces_s = (f64bits.from_bits(v) for v in
                                       (comp_s, t0_s, pieces_s))
@@ -1034,10 +1108,21 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
                 t0_pt[pi] = t0_s[d, off:off + n]
                 pieces_pt[pi] = pieces_s[d, :, off:off + n]
                 off += n
-        info = dict(path="device", devices=D,
-                    rounds=int(np.max(rounds_s)), device_s=device_s)
+        cols = _closed_fold(points, built, qs, comp_pt, t0_pt, pieces_pt)
+    info = dict(path="device", devices=D, rounds=int(np.max(rounds_s)),
+                device_s=device_s, spans=spans,
+                changed=int(np.sum(changed_s)),
+                op_rounds=sum(int(r) * b["n"]
+                              for r, b in zip(rounds_s, blks)),
+                grid_slots=D * R_max * Ls_max)
+    return SweepResult(points, cols, walltime() - t_wall, info)
 
-    # ---- fold into per-point RecordArray-style aggregates ----
+
+def _closed_fold(points: Sequence[SweepPoint], built: Sequence[dict],
+                 qs: Tuple[float, ...], comp_pt, t0_pt, pieces_pt
+                 ) -> Dict[str, np.ndarray]:
+    """Fold the converged per-point completions, arrivals and span
+    pieces into per-point RecordArray-style aggregate columns."""
     N = len(points)
     names = ("mean_latency", "read_latency", "update_latency",
              "local_latency", "global_latency", "update_global_latency")
@@ -1094,4 +1179,4 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
             tails[:, pi] = np.percentile(lat, qs)
     for q, t in zip(qs, tails):
         cols[f"p{q:g}_latency"] = t
-    return SweepResult(points, cols, walltime() - t_wall, info)
+    return cols
