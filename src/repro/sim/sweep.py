@@ -723,9 +723,13 @@ def _closed_pad(blk: dict, n_max: int, R_max: int, Ls_max: int
       O(R*Ls) slot padding in the sort).
     * ``rank``/``dest`` — sorted *position* -> (queue rank, slot in the
       rectangular scan grid).  Row sizes are static, so position ``p``
-      always lands in the same row at the same rank; the sorted
-      arrivals scatter into the (R, Ls) max-plus grid through these
-      static indices (pad positions index out of bounds and drop).
+      always lands in the same row at the same rank; departures gather
+      back from the (R, Ls) max-plus grid through ``dest`` (pad
+      positions index out of bounds and read the fill).
+    * ``src`` — the inverse map, grid slot -> sorted position
+      (``src[dest[p]] == p``); slots no position covers hold ``n_max``,
+      out of bounds.  The sorted arrivals and services fill the grid by
+      a gather through it, not a scatter through ``dest``.
     * ``seg``  — segment id of each op's (row, key) group, so the
       seen-before LRU mask reduces to one ``segment_min`` over queue
       ranks instead of a sort-by-key round trip.
@@ -756,7 +760,10 @@ def _closed_pad(blk: dict, n_max: int, R_max: int, Ls_max: int
     comp_key = (row_of.astype(np.int64) * (int(flat["key"].max()) + 2)
                 + flat["key"] + 1)
     seg = np.unique(comp_key, return_inverse=True)[1].astype(np.int32)
-    aux = dict(row=row_of, rank=rank, dest=dest, seg=seg)
+    src = np.full(R_max * Ls_max, n_max, np.int32)
+    covered = dest < R_max * Ls_max
+    src[dest[covered]] = np.flatnonzero(covered)
+    aux = dict(row=row_of, rank=rank, dest=dest, src=src, seg=seg)
     return flat, aux
 
 
@@ -798,7 +805,7 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
 
     # each stage of the round runs under a named scope, so that a device
     # trace attributes every op to its stage by the name in its
-    # ``tf_op`` metadata (``.../closed.to_grid/scatter``)
+    # ``tf_op`` metadata (``.../closed.order/sort``)
     def one_round(comp, flat, aux, pieces=None):
         n = comp.shape[0]
         with jax.named_scope("closed.arrival"):
@@ -829,14 +836,14 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
                              jnp.zeros((), dtype),
                              jnp.asarray(seek_v, dtype))
             svc_ord = add(jnp.take(flat["svc_base"], perm), pens)
-        # scatter the ordered queues into the rectangular (R, Ls) grid
-        # through the static position -> slot map (uncovered slots stay
+        # gather the ordered queues into the rectangular (R, Ls) grid
+        # through the static slot -> position map (uncovered slots read
         # +inf/0 and are never gathered back), then the departure scan
         with jax.named_scope("closed.to_grid"):
-            grid_a = jnp.full((R * Ls,), inf, dtype).at[
-                aux["dest"]].set(arr_ord, mode="drop").reshape(R, Ls)
-            grid_s = jnp.zeros((R * Ls,), dtype).at[
-                aux["dest"]].set(svc_ord, mode="drop").reshape(R, Ls)
+            grid_a = jnp.take(arr_ord, aux["src"], mode="fill",
+                              fill_value=inf).reshape(R, Ls)
+            grid_s = jnp.take(svc_ord, aux["src"], mode="fill",
+                              fill_value=0).reshape(R, Ls)
         with jax.named_scope("closed.depart"):
             dep_grid = depart(grid_a, grid_s)
         with jax.named_scope("closed.from_grid"):

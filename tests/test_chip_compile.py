@@ -29,6 +29,8 @@ from repro.sim.sweep import (SweepPoint, _closed_assemble,
 from repro.sim.f64bits import to_bits
 from repro.sim.vectorized import _DelayModel
 
+from test_sweep_names import _op_names
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -85,6 +87,9 @@ def test_closed_round_program_compiles_for_v5e(one_chip):
     out = compiled.out_info
     assert out[0].shape == (n,) and out[0].dtype == jnp.int64
     assert out[4].shape == (6, n)
+    # the chip's compiler keeps the scan grid's fill a gather
+    assert not [name for name in _op_names(compiled.as_text(), "scatter")
+                if "closed.to_grid" in name]
 
 
 def test_open_program_compiles_for_v5e(one_chip):

@@ -13,6 +13,7 @@ from repro.sim.sweep import SweepPoint, closed_grid, run_sweep
 
 from test_sweep import (TOL, assert_point_matches, measured_speedup,
                         strict_perf_floor)
+from test_sweep_names import CONTENDED, _block
 
 
 def closed_reference(p: SweepPoint, seed: int = 0,
@@ -44,6 +45,33 @@ def test_closed_sweep_matches_fast_engine_per_point():
     assert len(res) == len(pts)
     for i, p in enumerate(pts):
         assert_point_matches(res.row(i), closed_reference(p))
+
+
+def test_closed_pad_src_inverts_dest():
+    """The grid fill's gather index ``src`` is the exact inverse of
+    ``dest`` on the slots real ops cover; every other slot reads out of
+    bounds, and no pad position enters the grid."""
+    built, _, aux, static, _ = _block(CONTENDED)
+    R, Ls = static[-2:]
+    dest, src, row = aux["dest"], aux["src"], aux["row"]
+    n_max, n_real = row.shape[0], sum(b["n"] for b in built)
+    assert n_max > n_real                                  # pad ops
+    assert len(set(np.bincount(row[row < R]))) > 1         # ragged rows
+    assert src.shape == (R * Ls,) and src.dtype == np.int32
+    inb = dest < R * Ls
+    assert np.array_equal(np.flatnonzero(inb), np.arange(n_real))
+    assert np.array_equal(src[dest[inb]], np.flatnonzero(inb))
+    uncovered = np.ones(R * Ls, bool)
+    uncovered[dest[inb]] = False
+    assert np.all(src[uncovered] >= n_max)
+    assert not np.isin(np.arange(n_real, n_max), src).any()
+    # the gather fills the grid exactly as the scatter through dest did
+    vals = np.random.default_rng(0).random(n_max)
+    scattered = np.full(R * Ls, np.inf)
+    scattered[dest[inb]] = vals[inb]
+    gathered = np.where(src < n_max, vals[np.minimum(src, n_max - 1)],
+                        np.inf)
+    assert np.array_equal(gathered, scattered)
 
 
 def test_closed_sweep_mean_hops_and_ops_columns():

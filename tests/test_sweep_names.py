@@ -47,11 +47,22 @@ def _block(points, seed=0):
     return built, flat, aux, static, dm
 
 
-def test_compiled_closed_program_names_every_stage():
+def _closed_program_text():
     _, flat, aux, static, _ = _block(closed_grid(threads=4, ops=16)[:2])
     with jax.enable_x64(True):
-        text = jax.jit(sweep._closed_round_fn(*static)).lower(
+        return jax.jit(sweep._closed_round_fn(*static)).lower(
             flat, aux).compile().as_text()
+
+
+def _op_names(text, opcode):
+    """op_name of every ``opcode`` instruction of a compiled program."""
+    return [m.group(1) if m else "" for line in text.splitlines()
+            if re.search(rf"\s{opcode}\(", line)
+            for m in [re.search(r'op_name="([^"]*)"', line)]]
+
+
+def test_compiled_closed_program_names_every_stage():
+    text = _closed_program_text()
     names = set(re.findall(r'op_name="([^"]*)"', text))
     scopes = {part for name in names for part in name.split("/")
               if part.startswith("closed.")}
@@ -61,6 +72,19 @@ def test_compiled_closed_program_names_every_stage():
     assert any("/while/body/closed.to_grid/" in n for n in names)
     assert any("closed.replay/closed.to_grid/" in n for n in names)
     assert not any(part == "sweep" for n in names for part in n.split("/"))
+
+
+def test_grid_fill_gathers_and_never_scatters():
+    """The scan grid is filled by gathers through the static slot ->
+    position map, in the loop body and in the replay: no scatter runs
+    under ``closed.to_grid``."""
+    text = _closed_program_text()
+    gathers = _op_names(text, "gather")
+    for where in ("/while/body/closed.to_grid/",
+                  "closed.replay/closed.to_grid/"):
+        assert sum(where in n for n in gathers) == 2, where
+    assert not [n for n in _op_names(text, "scatter")
+                if "closed.to_grid" in n]
 
 
 @pytest.mark.parametrize("loop", ["open", "closed"])
