@@ -251,7 +251,9 @@ def run_window(system, seconds: float, annotate) -> dict:
     """Submit sweeps back to back; the first starts at once, later ones
     only inside ``seconds``, and the window ends when the last returns.
     Each sweep keeps its own wall time, ``run_sweep``'s and its device
-    call's, so that a slow one shows where it lost the time."""
+    call's, so that a slow one shows where it lost the time, and what
+    the program names in ``info``: its ``spans`` and every number under
+    its own name (counters such as ``changed``), for the readers."""
     sweeps, results, failed = [], [], 0
     t_start = time.perf_counter()
     t_end = t_start
@@ -272,18 +274,23 @@ def run_window(system, seconds: float, annotate) -> dict:
             log(f"sweep left the device path: {info}")
             failed += 1
             continue
-        sweeps.append(dict(wall_s=t_end - t, walltime_s=res.walltime_s,
-                           device_s=info["device_s"],
-                           rounds=info.get("rounds"),
-                           ops=int(res.columns["ops"].sum())))
+        sweeps.append(dict(
+            {k: v for k, v in info.items() if isinstance(v, (int, float))},
+            spans=info.get("spans"), wall_s=t_end - t,
+            walltime_s=res.walltime_s, device_s=info["device_s"],
+            rounds=info.get("rounds"), ops=int(res.columns["ops"].sum())))
         results.append(columns(res))
     return dict(sweeps=sweeps, results=results, failed=failed,
                 attempted=len(sweeps) + failed, window_s=t_end - t_start)
 
 
 def trace_window(jax, system, devs, trace_dir: Path) -> dict:
-    """Whole sweeps under the profiler, then the trace reduced."""
+    """Whole sweeps under the profiler, then the trace reduced: by
+    opcode (:mod:`trace_reduce`) and, for the accepted attempt, by the
+    program's named scopes (:mod:`scope_reduce`, under ``scopes``)."""
+    import scope_reduce
     import trace_reduce
+    import xspace
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 2
@@ -300,10 +307,13 @@ def trace_window(jax, system, devs, trace_dir: Path) -> dict:
         failed += win["failed"]
         t = time.perf_counter()
         try:
-            pd = trace_reduce.load(trace_reduce.newest_trace(trace_dir))
+            path = trace_reduce.newest_trace(trace_dir)
+            pd = trace_reduce.load(path)
             lost = trace_reduce.dropped(pd)
             if not lost:
                 red = trace_reduce.reduce_profile(pd, n_chips=len(devs))
+                red["scopes"] = scope_reduce.reduce_scopes(
+                    pd, xspace.tf_ops(Path(path).read_bytes()), len(devs))
                 break
         except trace_reduce.TraceError as e:
             raise HarnessError(f"trace: {e}") from e
@@ -392,7 +402,7 @@ def measure(args, t_proc: float, make_system: Callable = System) -> dict:
         log(f"trace: {red['sweeps']} sweeps, busy {red['busy_s']} s of "
             f"{red['window_s']} s, device calls {red['device_s']} s "
             f"(busy/calls {max(red['busy_s']) / red['device_s']}), "
-            f"groups {red['groups']}")
+            f"groups {red['groups']}, scopes {red['scopes']}")
         device.update(busy_s=statistics.fmean(red["busy_s"]),
                       window_s=red["window_s"])
         traced = {s["rounds"] for s in tw["sweeps"]}

@@ -1,14 +1,9 @@
-"""Device seconds per sweep of the departure scan: the sequential
-``lax.scan`` inside the fixed point's round loop.  Nothing where the
-trace lacks the structure this assumes: a scan step that runs once per
-slot of the longest queue in every round."""
+"""Device seconds per sweep of the closed round's departure scan (named
+scope ``closed.depart``: the sequential max-plus ``lax.scan`` over the
+grid and its transposes), its rounds and its share of the replay
+together.  Nothing where the trace has no such scope."""
+import scope_reduce
 
 
 def read(run):
-    t = run["trace"]
-    s = t["groups"].get("scan")
-    if not s or not t["sweeps"] or run["rounds"] is None:
-        return None
-    if t["runs"]["scan"] != run["rounds"] * run["queue_len"]:
-        return None
-    return s / t["sweeps"]
+    return scope_reduce.per_sweep(run, "closed.depart")

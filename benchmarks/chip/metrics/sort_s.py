@@ -1,13 +1,9 @@
-"""Device seconds per sweep of the round's composite ``lax.sort``.
-Nothing where the trace lacks the structure this assumes: one sort in
-every round, and one in the replay of the converged round."""
+"""Device seconds per sweep of the closed round's queue order (named scope
+``closed.order``: the composite stable ``lax.sort`` by row and arrival),
+its rounds and its share of the replay together.  Nothing where the
+trace has no such scope; a sort under another scope does not count."""
+import scope_reduce
 
 
 def read(run):
-    t = run["trace"]
-    s = t["groups"].get("sort")
-    if not s or not t["sweeps"] or run["rounds"] is None:
-        return None
-    if t["runs"]["sort"] != run["rounds"] + 1:
-        return None
-    return s / t["sweeps"]
+    return scope_reduce.per_sweep(run, "closed.order")

@@ -8,9 +8,12 @@ import re
 import shutil
 import subprocess
 import sys
+from contextlib import nullcontext
 from itertools import product
 from pathlib import Path
+from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 import harness
@@ -22,8 +25,9 @@ SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
-def test_cell_files_found_by_name(cell):
+def check_cell(cell: str) -> None:
+    """What holds for any cell: its files found by name, its
+    configuration's cuts as the spec lists them, its grid whole."""
     spec, w, config, traffic = harness.load_cell(cell)
     entry = next(c for c in spec["configs"] if c["name"] == w["config"])
     assert entry["file"] == f"benchmarks/chip/configs/{w['config']}.json"
@@ -36,6 +40,39 @@ def test_cell_files_found_by_name(cell):
         set(product(*axes.values()))
     for p in points:
         assert p["groups"] >= 1 and p["threads"] >= 1
+
+
+def check_names(spec: dict) -> None:
+    """Names as the contract has them: unique, of its letters, and
+    ``setup_s`` among the end-to-end metrics."""
+    names = [c["name"] for c in spec["configs"]] + \
+        [w["name"] for w in spec["workloads"]] + \
+        [m["name"] for m in spec["end_to_end"] + spec["per_layer"]]
+    assert len(names) == len(set(names))
+    assert all(NAME.match(n) for n in names)
+    assert "setup_s" in {m["name"] for m in spec["end_to_end"]}
+
+
+def check_metrics(spec: dict) -> None:
+    """What holds for any cell and entry: a reader file for every
+    per-layer entry, its ``workloads`` naming only cells of the spec and
+    its ``moves`` an end-to-end metric; every cell reports
+    ``sim_ops_per_s``, ``setup_s`` and a per-layer metric."""
+    cells = {w["name"] for w in spec["workloads"]}
+    e2e = {m["name"] for m in spec["end_to_end"]}
+    for m in spec["per_layer"]:
+        assert callable(harness.load_reader(m["name"])), m["name"]
+        assert m["workloads"] and set(m["workloads"]) <= cells, m["name"]
+        assert m["moves"] in e2e, m["name"]
+    for c in cells:
+        got = {m["name"] for m in harness.cell_metrics(spec, c, trace=False)}
+        assert {"sim_ops_per_s", "setup_s"} <= got, c
+        assert harness.cell_metrics(spec, c, trace=True), c
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
+def test_cell_files_found_by_name(cell):
+    check_cell(cell)
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
@@ -78,15 +115,7 @@ def test_workload_seeds_give_the_same_shapes(cell):
 
 
 def test_names_and_paths_keep_the_contract():
-    names = [c["name"] for c in SPEC["configs"]] + \
-        [w["name"] for w in SPEC["workloads"]] + \
-        [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]]
-    assert len(names) == len(set(names))
-    assert all(NAME.match(n) for n in names)
-    e2e = {m["name"] for m in SPEC["end_to_end"]}
-    assert "setup_s" in e2e
-    for m in SPEC["per_layer"]:
-        assert m["moves"] in e2e
+    check_names(SPEC)
     for path in SPEC["paths"]:
         assert (ROOT / path).is_dir()
 
@@ -117,52 +146,149 @@ def test_new_traffic_file_is_picked_up_by_name(tmp_path, monkeypatch):
 
 
 def test_every_metric_has_its_reader():
-    for m in SPEC["per_layer"]:
-        assert callable(harness.load_reader(m["name"]))
-    closed = [c["name"] for c in SPEC["workloads"]
-              if harness.load_cell(c["name"])[3]["loop"] == "closed"]
-    for c in SPEC["workloads"]:
-        got = {m["name"] for m in harness.cell_metrics(SPEC, c["name"],
-                                                        trace=True)}
-        assert {"host_s", "device_call_s", "device_idle_share"} <= got
-        assert ("scan_s" in got) == (c["name"] in closed)
-        e2e = {m["name"] for m in harness.cell_metrics(SPEC, c["name"],
-                                                        trace=False)}
-        assert {"sim_ops_per_s", "setup_s"} <= e2e
+    check_metrics(SPEC)
+    got = {m["name"] for m in harness.cell_metrics(SPEC, "paper-closed",
+                                                    trace=True)}
+    assert {"host_s", "device_call_s", "device_idle_share", "scan_s"} <= got
+
+
+def _snapshot(root: Path) -> dict:
+    return {p.relative_to(root): p.read_bytes()
+            for p in sorted(root.rglob("*")) if p.is_file()}
+
+
+def test_a_cell_its_counter_and_its_metric_are_added_as_data(
+        tmp_path, monkeypatch):
+    """A later PR adds a closed cell on a configuration and traffic file
+    of its own, and a per-layer metric whose reader reads a counter the
+    program puts in ``info``, by new files and new entries alone."""
+    root = tmp_path / "co"
+    bench = root / "benchmarks" / "chip"
+    shutil.copytree(BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    before = _snapshot(bench)
+    config = json.loads((bench / "configs" / "edgekv-paper.json")
+                        .read_text())
+    config["service"] = dict(config["service"], page_cache_keys=2500)
+    (bench / "configs" / "edgekv-paper-evict.json").write_text(
+        json.dumps(config))
+    (bench / "traffic" / "closed-evict-grid.json").write_text(json.dumps(
+        {"workload_seeds": [549, 8975], "loop": "closed",
+         "axes": {"p_global": [0.0, 1.0]}, "devices": 1}))
+    (bench / "metrics" / "capacity_miss_share.py").write_text(
+        '"""Made-up reader of a made-up counter."""\n\n\n'
+        "def read(run):\n"
+        "    v = [s.get('capacity_misses') for s in run['sweeps']]\n"
+        "    if not v or None in v:\n"
+        "        return None\n"
+        "    return 100.0 * sum(v) / sum(s['ops'] for s in run['sweeps'])\n")
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append(dict(
+        spec["configs"][0], name="edgekv-paper-evict",
+        file="benchmarks/chip/configs/edgekv-paper-evict.json"))
+    spec["workloads"].append(dict(
+        name="paper-evict", config="edgekv-paper-evict",
+        traffic="closed-evict-grid", chips=1, why="a cell added as data"))
+    spec["per_layer"].append(dict(
+        name="capacity_miss_share", unit="%", better="lower",
+        source="program_counter", layer="closed fixed point",
+        moves="sim_ops_per_s", workloads=["paper-evict"]))
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    after = _snapshot(bench)
+    assert {k: after[k] for k in before} == before  # no file touched
+    assert len(after) == len(before) + 3
+    for group, entries in SPEC.items():   # the spec only gains entries
+        if isinstance(entries, list):
+            assert spec[group][:len(entries)] == entries, group
+        else:
+            assert spec[group] == entries, group
+
+    monkeypatch.setattr(harness, "ROOT", root)
+    monkeypatch.setattr(harness, "BENCH", bench)
+    check_names(spec)
+    check_metrics(spec)
+    for cell in ("paper-closed", "paper-evict"):
+        check_cell(cell)
+    got = {m["name"] for m in harness.cell_metrics(spec, "paper-evict",
+                                                    trace=True)}
+    assert got == {"capacity_miss_share"}
+
+    class Sweep:   # what run_sweep returns, with the new counter
+        info = dict(path="device", device_s=0.5, rounds=3,
+                    capacity_misses=45, spans={"run_sweep.build": 0.01})
+        walltime_s = 0.6
+        columns = {"ops": np.array([450.0, 450.0])}
+
+        def __len__(self):
+            return 2
+
+    system = SimpleNamespace(sweep=Sweep)
+    win = harness.run_window(system, 0.0, lambda name: nullcontext())
+    run = dict(sweeps=win["sweeps"], trace={}, loop="closed", rounds=3)
+    assert harness.load_reader("capacity_miss_share")(run) == 5.0
 
 
 def test_readers_read_what_is_there():
-    sweeps = [dict(walltime_s=2.0, device_s=1.5, rounds=20),
-              dict(walltime_s=3.0, device_s=2.5, rounds=21)]
+    spans = {"run_sweep.build": 0.01, "run_sweep.fold": 0.004}
+    sweeps = [dict(walltime_s=2.0, device_s=1.5, rounds=20, ops=900,
+                   spans=spans, changed=300, op_rounds=18_000,
+                   grid_slots=1800),
+              dict(walltime_s=3.0, device_s=2.5, rounds=21, ops=900,
+                   spans=dict(spans, **{"run_sweep.build": 0.03}),
+                   changed=600, op_rounds=18_900, grid_slots=1800)]
+    scopes = {"closed.arrival": 0.02, "closed.order": 0.2,
+              "closed.lru": 0.06, "closed.to_grid": 0.08,
+              "closed.depart": 0.4, "closed.from_grid": 0.1,
+              "closed.completion": 0.004, "unscoped": 0.386}
     trace = dict(busy_s=[0.75, 0.5], window_s=1.0, sweeps=2,
                  groups={"sort": 0.2, "scan": 0.4},
-                 runs={"scan": 21 * 500, "sort": 22})
+                 runs={"scan": 21 * 500, "sort": 22}, scopes=scopes)
     run = dict(sweeps=sweeps, trace=trace, loop="closed", rounds=21,
                queue_len=500)
     read = {m["name"]: harness.load_reader(m["name"])(run)
             for m in SPEC["per_layer"]}
-    assert read == dict(host_s=0.5, device_call_s=2.0,
-                        device_idle_share=50.0, fixed_point_rounds=21,
-                        sort_s=0.1, scan_s=0.2)
-    open_run = dict(sweeps=[dict(s, rounds=None) for s in sweeps],
-                    trace=dict(trace, groups={"other": 1.0}), loop="open",
-                    rounds=None)
-    for name in ("fixed_point_rounds", "sort_s", "scan_s"):
-        assert harness.load_reader(name)(open_run) is None
+    known = dict(host_s=0.5, device_call_s=2.0, device_idle_share=50.0,
+                 fixed_point_rounds=21, sort_s=0.1, scan_s=0.2,
+                 arrival_s=0.01, lru_s=0.03, to_grid_s=0.04,
+                 from_grid_s=0.05, completion_s=0.002, build_s=0.02,
+                 fold_s=0.004, round_useful_share=100.0 * 900 / 36_900,
+                 grid_fill=50.0)
+    assert {k: read[k] for k in known} == pytest.approx(known, rel=1e-12)
+    open_run = dict(sweeps=[dict(s, rounds=None, changed=None,
+                                 op_rounds=None, grid_slots=None)
+                            for s in sweeps],
+                    trace=dict(trace, groups={"other": 1.0},
+                               scopes={"unscoped": 1.0}),
+                    loop="open", rounds=None)
+    got = {m["name"]: harness.load_reader(m["name"])(open_run)
+           for m in SPEC["per_layer"]}
+    for name in ("fixed_point_rounds", "sort_s", "scan_s", "arrival_s",
+                 "lru_s", "to_grid_s", "from_grid_s", "completion_s",
+                 "round_useful_share", "grid_fill"):
+        assert got[name] is None, name
+    for name in set(read) - set(known):   # entries later PRs add
+        for v in (read[name], got[name]):
+            assert v is None or isinstance(v, (int, float)), name
 
 
-@pytest.mark.parametrize("name,runs", [
-    ("scan_s", {"scan": 21 * 500 - 1, "sort": 22}),   # a step short
-    ("scan_s", {"scan": 9, "sort": 22}),              # scan not a loop
-    ("sort_s", {"scan": 21 * 500, "sort": 44}),       # two sorts a round
-    ("sort_s", {"scan": 21 * 500, "sort": 0}),
+@pytest.mark.parametrize("name,scopes,sweeps,want", [
+    ("sort_s", {"unscoped": 0.75}, 1, None),               # no such scope
+    ("sort_s", {"closed.order": 0.2, "closed.from_grid": 0.2}, 1, 0.2),
+    ("sort_s", {"closed.order": 0.2}, 0, None),            # no sweeps
+    ("scan_s", {"closed.depart": 0.4, "closed.replay": 0.1}, 2, 0.2),
 ])
-def test_readers_read_nothing_from_a_trace_of_another_shape(name, runs):
-    trace = dict(busy_s=[0.75], window_s=1.0, sweeps=1,
-                 groups={"sort": 0.2, "scan": 0.4}, runs=runs)
+def test_readers_read_nothing_from_a_trace_of_another_shape(
+        name, scopes, sweeps, want):
+    """The scan and sort readers take their scope's seconds alone: a sort
+    under another scope, or a step count other than the opcode groups'
+    (``runs``), leaves them as they are."""
+    trace = dict(busy_s=[0.75], window_s=1.0, sweeps=sweeps,
+                 groups={"sort": 0.4, "scan": 0.3},
+                 runs={"scan": 9, "sort": 44}, scopes=scopes)
     run = dict(sweeps=[dict(walltime_s=2.0, device_s=1.5, rounds=21)],
                trace=trace, loop="closed", rounds=21, queue_len=500)
-    assert harness.load_reader(name)(run) is None
+    assert harness.load_reader(name)(run) == want
 
 
 @pytest.mark.parametrize("ops,window,want", [

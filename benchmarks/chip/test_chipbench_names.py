@@ -17,9 +17,9 @@ import xspace as X
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 OLD = FIXTURES / "closed_sweep.xplane.pb.gz"
 SCOPED = FIXTURES / "closed_sweep_scoped.xplane.pb.gz"
-SCOPE_READERS = {"arrival_s": "closed.arrival", "order_s": "closed.order",
+SCOPE_READERS = {"arrival_s": "closed.arrival", "sort_s": "closed.order",
                  "lru_s": "closed.lru", "to_grid_s": "closed.to_grid",
-                 "depart_s": "closed.depart",
+                 "scan_s": "closed.depart",
                  "from_grid_s": "closed.from_grid",
                  "completion_s": "closed.completion"}
 PROGRAM_READERS = ("build_s", "fold_s", "round_useful_share", "grid_fill")
@@ -112,8 +112,10 @@ def test_readers_read_nothing_from_the_unscoped_trace():
     run = _run(red)
     for name in list(SCOPE_READERS) + list(PROGRAM_READERS):
         assert harness.load_reader(name)(run) is None, name
-    # what the harness read before stays readable
-    assert harness.load_reader("sort_s")(dict(run, rounds=10)) > 0
+    # that program named no scopes, so the sort and the scan read nothing
+    # there, even where the opcode groups have their seconds
+    assert red["groups"]["sort"] > 0 and red["groups"]["scan"] > 0
+    assert harness.load_reader("sort_s")(dict(run, rounds=10)) is None
 
 
 def test_readers_of_the_programs_spans_and_counters():
@@ -131,7 +133,7 @@ def test_readers_of_the_programs_spans_and_counters():
         round_useful_share=100.0 * 687_749 / 4_590_000,
         grid_fill=100.0 * 45_000 / 88_440), rel=1e-12)
     assert harness.load_reader("lru_s")(run) == 0.25
-    assert harness.load_reader("order_s")(run) is None
+    assert harness.load_reader("sort_s")(run) is None
     # one sweep of the parent's program, which reports none of them
     del sweeps[1]["spans"], sweeps[1]["changed"], sweeps[1]["grid_slots"]
     for name in PROGRAM_READERS:

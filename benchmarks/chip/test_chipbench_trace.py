@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import gzip
 from contextlib import nullcontext
+from itertools import count
 from pathlib import Path
 from types import SimpleNamespace as NS
 
@@ -11,6 +12,7 @@ import numpy as np
 import pytest
 
 import harness
+import scope_reduce as S
 import trace_reduce as T
 
 FIXTURE = Path(__file__).resolve().parent / "fixtures" / \
@@ -123,38 +125,73 @@ def test_reduce_recorded_closed_sweep():
     assert red["runs"] == {"scan": 4590.0, "sort": 11.0}
 
 
+class _Sweep:
+    """What ``run_sweep`` returns on the device path, made up."""
+    info = dict(path="device", device_s=0.5)
+    walltime_s = 0.6
+    columns = {"ops": np.array([10.0])}
+
+    def __len__(self):
+        return 1
+
+
 def test_a_stretch_that_dropped_events_is_traced_again(monkeypatch,
                                                        tmp_path):
     calls = []
 
-    class Sweep:
-        info = dict(path="device", device_s=0.5)
-        walltime_s = 0.6
-        columns = {"ops": np.array([10.0])}
-
-        def __len__(self):
-            return 1
-
     class System:
         def sweep(self):
             calls.append("sweep")
-            return Sweep()
+            return _Sweep()
 
     losses = iter([7, 3, 0])
+    attempts = count(1)
     fake_jax = NS(profiler=NS(
         ProfileOptions=NS, TraceAnnotation=lambda name: nullcontext(),
         start_trace=lambda *a, **k: calls.append("start"),
         stop_trace=lambda: calls.append("stop")))
-    monkeypatch.setattr(T, "newest_trace", lambda d: "x.xplane.pb")
-    monkeypatch.setattr(T, "load", lambda path: "profile")
+    trace = tmp_path / "x.xplane.pb"
+    trace.write_bytes(b"")
+    monkeypatch.setattr(T, "newest_trace", lambda d: str(trace))
+    monkeypatch.setattr(T, "load", lambda path: f"profile {next(attempts)}")
     monkeypatch.setattr(T, "dropped", lambda pd: next(losses))
     monkeypatch.setattr(T, "reduce_profile", lambda pd, n_chips: dict(
         busy_s=[0.25], window_s=1.0, groups={}, runs={}, breakdown={}))
+    scoped = []
+    monkeypatch.setattr(S, "reduce_scopes", lambda pd, names, n_chips: (
+        scoped.append((pd, names, n_chips)) or {"unscoped": 0.25}))
     monkeypatch.setattr(harness, "TRACE_SECONDS", 0.0)
     out = harness.trace_window(fake_jax, System(), [None], tmp_path / "t")
     assert calls == ["start", "sweep", "stop"] * 3
     assert len(out["results"]) == 3 and out["trace"]["sweeps"] == 1
+    # the scopes of the accepted attempt alone
+    assert scoped == [("profile 3", {}, 1)]
+    assert out["trace"]["scopes"] == {"unscoped": 0.25}
     losses = iter([1, 1, 1])
     monkeypatch.setattr(T, "dropped", lambda pd: next(losses))
     with pytest.raises(harness.HarnessError, match="dropped in 3 tries"):
         harness.trace_window(fake_jax, System(), [None], tmp_path / "t")
+
+
+def test_window_hands_the_programs_spans_and_counters_to_the_readers():
+    """Each sweep keeps ``info``'s spans and every number under its own
+    name, a counter that no reader knows yet among them; the harness's
+    own timings are kept as they were."""
+    spans = {"run_sweep.build": 0.01, "run_sweep.fold": 0.004}
+
+    class Sweep(_Sweep):
+        info = dict(path="device", device_s=0.5, rounds=7, changed=12,
+                    new_counter=3.5, ops=-1, spans=spans, note="text")
+
+    win = harness.run_window(NS(sweep=Sweep), 0.0,
+                             lambda name: nullcontext())
+    [sweep] = win["sweeps"]
+    assert sweep.pop("wall_s") >= 0.0
+    assert sweep == dict(device_s=0.5, rounds=7, changed=12,
+                         new_counter=3.5, spans=spans, walltime_s=0.6,
+                         ops=10)
+    # a program that names nothing gives the readers nothing
+    [bare] = harness.run_window(NS(sweep=_Sweep), 0.0,
+                                lambda name: nullcontext())["sweeps"]
+    assert bare["spans"] is None and "changed" not in bare
+    assert harness.load_reader("build_s")(dict(sweeps=[bare])) is None

@@ -17,9 +17,9 @@ attributed by their HLO opcode and by how often they run:
   of the departure scan nested inside the round loop do; ``sort`` is
   every ``sort`` instruction; everything else is ``other``;
 * runs: on the first chip, per program execution, the most runs of any
-  ``scan`` op and the runs of all ``sort`` ops together, so that a
-  reader can check the trace has the structure it assumes (a scan step
-  run once per queue slot per round, one sort per round);
+  ``scan`` op and the runs of all ``sort`` ops together, which the
+  harness logs beside ``groups`` (the readers take a stage's seconds by
+  the program's scope names, :mod:`scope_reduce`);
 * breakdown: the leaf ops that took most device time, and the longest
   idle gaps on chip 0, each named by the innermost host event that
   covers its middle.

@@ -4,7 +4,8 @@ A JAX profiler trace (``.xplane.pb``) is a serialized ``XSpace``
 protocol buffer.  Each plane keeps one ``XEventMetadata`` per distinct
 event, and for a device op that metadata carries the op's ``tf_op``
 stat: the name stack of the jitted program (``jit(run)/while/body/
-closed.to_grid/scatter``), which ``jax.named_scope`` extends.
+closed.to_grid/gather``, one of the two static gathers that fill the
+scan grid), which ``jax.named_scope`` extends.
 ``jax.profiler.ProfileData`` does not expose event-metadata stats, so
 this module reads them from the wire format itself, with nothing but the
 standard library.  It reads only what it needs::
