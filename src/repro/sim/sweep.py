@@ -17,7 +17,8 @@ program iterates a batched round to that fixed point inside one
 ``lax.while_loop``: completions -> next arrivals (elementwise
 :func:`~repro.sim.vectorized.arrival_chain`) -> per-row stable sort into
 leader-arrival order (ties broken by flat position = the heap engine's
-pid order) -> seen-before page penalties -> batched max-plus departure
+pid order) -> LRU page penalties (seen-before, or the exact stack
+distance where a leader can evict) -> batched max-plus departure
 scan -> completions (:func:`~repro.sim.vectorized.completion_chain`).
 Unresolved ops (predecessor not yet computed) carry ``+inf`` arrivals,
 which sorts them harmlessly after every resolved op, so each round
@@ -49,8 +50,11 @@ whole grid.
 Only the parts that are inherently host-side stay in numpy: drawing the
 op schedules (the numpy RNG streams must match the fast engine draw for
 draw), Chord routing (one shared ring per group count, one ``route`` per
-(gateway, successor-vnode) class for the *whole grid*), and the exact
-LRU page-penalty masks (:func:`~repro.sim.vectorized.lru_hit_mask`).
+(gateway, successor-vnode) class for the *whole grid*), and the open
+loop's exact LRU page-penalty masks
+(:func:`~repro.sim.vectorized.lru_hit_mask`; its queue orders are fixed
+before the program runs, while the closed loop's change every round, so
+the closed round finds the same hits on the device).
 
 Exactness: every per-point result matches an independent
 ``SimEdgeKV(engine="fast")`` run on the same seeds to ~1e-13 relative —
@@ -149,20 +153,18 @@ class SweepResult:
     ``walltime_s`` is the host's wall time of the whole ``run_sweep``
     call.  ``info`` says how the grid ran:
 
-    * ``path`` — ``"device"``, or ``"host"`` for the closed loop's
-      eviction regime, whose fixed point runs in numpy;
+    * ``path`` — ``"device"`` (every grid runs on the device);
     * ``spans`` — wall seconds of ``run_sweep``'s named host steps
       (:func:`repro.obs.span`; the same names appear in a
       ``jax.profiler`` trace): ``run_sweep.build`` (schedules, routes,
       padding, bit patterns), ``run_sweep.dispatch`` (transfers and the
       enqueued call; trace and compile on a cold call),
       ``run_sweep.wait`` (``device_get``), ``run_sweep.fold`` (per-point
-      columns), and on the host path ``run_sweep.host_rounds`` in place
-      of dispatch and wait;
-    * ``device_s`` (device path) — wall seconds from the first transfer
-      through the final ``device_get``, so at least dispatch + wait;
+      columns);
+    * ``device_s`` — wall seconds from the first transfer through the
+      final ``device_get``, so at least dispatch + wait;
 
-    and on the closed loop's device path:
+    and on the closed loop:
 
     * ``devices`` — point blocks, one per device;
     * ``rounds`` — fixed-point rounds, the most of any block;
@@ -173,7 +175,11 @@ class SweepResult:
       the fixed point;
     * ``grid_slots`` — blocks x rows x longest queue, the slots of the
       departure scan's grid, of which the real ops fill
-      ``columns["ops"].sum()``.
+      ``columns["ops"].sum()``;
+    * ``capacity_misses`` — in the converged round, the real ops that
+      miss the leader's page cache although their key was served earlier
+      in the same queue (evicted since), summed over the blocks; 0 where
+      no leader can evict.
     """
     points: List[SweepPoint]
     columns: Dict[str, np.ndarray]
@@ -383,9 +389,10 @@ def run_sweep(points: Iterable[SweepPoint], *, duration: float = 2.0,
     ops-per-thread); non-convergence raises instead of returning wrong
     numbers.  Grids whose (config, group) rows can evict page-cache
     entries (distinct keys at one leader exceeding
-    ``service.page_cache_keys``) fall back to an equivalent host-side
-    fixed point with the exact LRU replay
-    (:func:`~repro.sim.vectorized.lru_hit_mask`).
+    ``service.page_cache_keys``) compile a program whose round adds the
+    exact LRU stack distance of every op (scope ``closed.lru_stack``),
+    with the hits of :func:`~repro.sim.vectorized.lru_hit_mask`; the
+    others compile without it.
 
     ``scan_backend`` selects the leader-stage scan.  ``None`` (default)
     resolves per loop mode: ``"assoc"`` (``jax.lax.associative_scan``,
@@ -732,7 +739,9 @@ def _closed_pad(blk: dict, n_max: int, R_max: int, Ls_max: int
       a gather through it, not a scatter through ``dest``.
     * ``seg``  — segment id of each op's (row, key) group, so the
       seen-before LRU mask reduces to one ``segment_min`` over queue
-      ranks instead of a sort-by-key round trip.
+      ranks instead of a sort-by-key round trip, and the stack distance
+      (:func:`_lru_stack`) finds each op's previous same-key op by one
+      sort on it.
 
     Padding is inert by construction: pad ops are first-ops with
     all-zero delay columns (their completions converge to a constant in
@@ -767,15 +776,68 @@ def _closed_pad(blk: dict, n_max: int, R_max: int, Ls_max: int
     return flat, aux
 
 
+# queue positions per block of the stack-distance count
+_STACK_BLOCK = 512
+
+
+def _lru_stack(seg_ord, capacity: int, Ls: int):
+    """Exact LRU page-cache hits of a block's ops in their current queue
+    order (sorted position ``j`` holds an op of static (row, key)
+    segment ``seg_ord[j]``; rows are contiguous runs of at most ``Ls``
+    positions).
+
+    Returns ``(prev, hit)``: ``prev[j]`` is the position of the previous
+    op of the same segment (-1 for none), and ``hit[j]`` says that fewer
+    than ``capacity`` distinct keys were touched strictly between the
+    two — :func:`~repro.sim.vectorized.lru_hit_mask`'s rule (stack
+    distance counting itself <= capacity, get-then-put).  Integer work
+    with static shapes: two sorts find ``prev``; the distinct keys in
+    ``(prev[j], j)`` are ``j - prev[j] - 1`` less the repeats there, the
+    positions ``k < j`` with ``prev[k] > prev[j]`` (such a ``k`` lies in
+    ``(prev[j], j)``, so in ``j``'s row, and repeats a key seen earlier
+    in that stretch).  They are counted pairwise over a window of the
+    ``Ls - 1`` positions before each block of ``_STACK_BLOCK`` queries.
+    """
+    n = seg_ord.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    # positions grouped by segment, in queue order within each
+    seg_s, pos_s = jax.lax.sort((seg_ord, pos), num_keys=1, is_stable=True)
+    same = jnp.concatenate([jnp.zeros((1,), bool), seg_s[1:] == seg_s[:-1]])
+    prev_s = jnp.where(same, jnp.roll(pos_s, 1), -1)
+    _, prev = jax.lax.sort((pos_s, prev_s), num_keys=1)
+    B = max(1, min(_STACK_BLOCK, Ls))
+    m = -(-(Ls - 1) // B)      # blocks back that a row can reach
+    nb = -(-n // B)
+    blocks = jnp.concatenate(
+        [jnp.full((m * B,), -1, jnp.int32), prev,
+         jnp.full((nb * B - n,), -1, jnp.int32)]).reshape(nb + m, B)
+    # query block b sees positions [(b - m) B, (b + 1) B), of which the
+    # ones before each query count
+    win = jnp.concatenate([blocks[i:i + nb] for i in range(m + 1)], axis=1)
+    q = blocks[m:]
+    before = (jnp.arange((m + 1) * B)[None, :]
+              < (m * B + jnp.arange(B))[:, None])
+    repeats = jnp.sum((win[:, None, :] > q[:, :, None]) & before, axis=2,
+                      dtype=jnp.int32).reshape(-1)[:n]
+    return prev, (prev >= 0) & (pos - prev - 1 - repeats < capacity)
+
+
 @lru_cache(maxsize=None)
 def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
-                     max_rounds: int, seek: float, R: int, Ls: int):
+                     max_rounds: int, seek: float, R: int, Ls: int,
+                     evict: bool = False, capacity: int = 0):
     """The raw (unjitted) fixed-point program for one device block.
 
     With the exact ``seq`` backend every time is the int64 bit pattern of
     its IEEE double (:mod:`repro.sim.f64bits`), added with integer ops,
     so queue orders and the convergence test are bit-for-bit the host's
-    on any device; the closed-form backends compute in float64."""
+    on any device; the closed-form backends compute in float64.
+
+    ``evict`` (some row holds more distinct keys than the page cache's
+    ``capacity``) adds the stage ``closed.lru_stack``, the exact LRU
+    stack distance of every op in each round's queue order
+    (:func:`_lru_stack`), and a third count, the replay's capacity
+    misses; without it the program has neither."""
     if scan_backend == "seq":
         add = partial(f64bits.add, jnp)
         dtype, inf, neg_inf = jnp.int64, f64bits.INF, f64bits.NEG_INF
@@ -806,7 +868,7 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
     # each stage of the round runs under a named scope, so that a device
     # trace attributes every op to its stage by the name in its
     # ``tf_op`` metadata (``.../closed.order/sort``)
-    def one_round(comp, flat, aux, pieces=None):
+    def one_round(comp, flat, aux, pieces=None, misses=None):
         n = comp.shape[0]
         with jax.named_scope("closed.arrival"):
             t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
@@ -824,16 +886,26 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
             _, arr_ord, perm = jax.lax.sort(
                 (aux["row"], arr, jnp.arange(n, dtype=jnp.int32)),
                 num_keys=2, is_stable=True)
-        # seen-before page penalties (the no-eviction LRU regime): an op
-        # hits iff a same-key op sits earlier in its queue, i.e. its
-        # rank exceeds the min rank of its static (row, key) segment;
-        # ranks per sorted position are static (row sizes don't change)
+        if evict:
+            with jax.named_scope("closed.lru_stack"):
+                prev, hit = _lru_stack(jnp.take(aux["seg"], perm),
+                                       capacity, Ls)
+                if misses is not None:
+                    # real ops served before in their queue that miss
+                    misses.append(jnp.sum(
+                        (prev >= 0) & ~hit & (aux["dest"] < R * Ls),
+                        dtype=jnp.int64))
+        # page penalties.  Without eviction an op hits iff a same-key op
+        # sits earlier in its queue, i.e. its rank exceeds the min rank
+        # of its static (row, key) segment; ranks per sorted position
+        # are static (row sizes don't change)
         with jax.named_scope("closed.lru"):
-            seg_ord = jnp.take(aux["seg"], perm)
-            rmin = jax.ops.segment_min(aux["rank"], seg_ord,
-                                       num_segments=n)
-            pens = jnp.where(aux["rank"] > rmin[seg_ord],
-                             jnp.zeros((), dtype),
+            if not evict:
+                seg_ord = jnp.take(aux["seg"], perm)
+                rmin = jax.ops.segment_min(aux["rank"], seg_ord,
+                                           num_segments=n)
+                hit = aux["rank"] > rmin[seg_ord]
+            pens = jnp.where(hit, jnp.zeros((), dtype),
                              jnp.asarray(seek_v, dtype))
             svc_ord = add(jnp.take(flat["svc_base"], perm), pens)
         # gather the ordered queues into the rectangular (R, Ls) grid
@@ -902,9 +974,10 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
                 t0 = jnp.where(flat["first"], jnp.zeros((), dtype),
                                jnp.take(comp, flat["pred"], mode="clip"))
             pieces: list = []
-            one_round(comp, flat, aux, pieces=pieces)
+            misses: list = []
+            one_round(comp, flat, aux, pieces=pieces, misses=misses)
             pieces = jnp.stack(pieces)
-        return comp, t0, done, jnp.stack([rounds, changed]), pieces
+        return comp, t0, done, jnp.stack([rounds, changed] + misses), pieces
 
     return run
 
@@ -912,13 +985,13 @@ def _closed_round_fn(max_hops: int, scan_backend: str, interpret: bool,
 @lru_cache(maxsize=None)
 def _closed_exe(max_hops: int, scan_backend: str, interpret: bool,
                 max_rounds: int, seek: float, R: int, Ls: int,
-                devices: int):
+                devices: int, evict: bool, capacity: int):
     """Cached executable wrappers (jit, or jit of a point-sharded
     ``jax.shard_map`` for ``devices`` > 1) around the round program —
     cached so repeat sweeps reuse the compiled program.
     """
     run = _closed_round_fn(max_hops, scan_backend, interpret, max_rounds,
-                           seek, R, Ls)
+                           seek, R, Ls, evict, capacity)
     if devices == 1:
         return jax.jit(run)
     return _shard_points(run, jax.devices()[:devices])
@@ -944,74 +1017,6 @@ def _shard_points(run, devs: Sequence) -> object:
                                  in_specs=(spec, spec),
                                  out_specs=(spec,) * 5,
                                  check_vma=False))
-
-
-def _closed_rounds_host(built: Sequence[dict], capacity: int, seek: float,
-                        max_hops: int, max_rounds: int
-                        ) -> Tuple[List[np.ndarray], List[np.ndarray],
-                                   List[np.ndarray]]:
-    """Host-side fixed point for grids in the eviction regime: same
-    rounds, same float64 expressions, but page penalties come from the
-    exact LRU replay (:func:`~repro.sim.vectorized.lru_hit_mask`, stack
-    distances and all) instead of the in-program seen-before mask.
-
-    Also returns the span-model pieces ``(b_request, b_route, arrival,
-    start, departure, b_replicate)`` stacked per point: the round that
-    detects convergence recomputes them from the already-converged
-    completions, so its intermediates ARE the fixed point's.
-    """
-    comp_pt, t0_pt, pieces_pt = [], [], []
-    for b in built:
-        flat, n = b["flat"], b["n"]
-        comp = np.full(n, np.inf)
-        t0 = np.zeros(n)
-        for _ in range(max_rounds):
-            t0 = np.where(flat["first"], 0.0, comp[flat["pred"]])
-            cuts: list = []
-            arr = arrival_chain(np, t0, flat["c_req"], flat["f_req"],
-                                flat["sg_req"], flat["h_req"],
-                                flat["lf"], flat["glob"], flat["hops"],
-                                max_hops, cuts=cuts)
-            dep = np.zeros(n)
-            start = np.zeros(n)
-            for m in b["rows"]:
-                order = m[np.argsort(arr[m], kind="stable")]
-                hitm = lru_hit_mask(flat["key"][order], capacity)
-                svc = flat["svc_base"][order] + np.where(hitm, 0.0, seek)
-                arr_o = arr[order].tolist()
-                svc_o = svc.tolist()
-                dep_o = np.empty(len(order))
-                start_o = np.empty(len(order))
-                d = -np.inf
-                # sequential recurrence in the engine's exact float
-                # order (start = max(a, free); dep = start + svc) —
-                # the closed-form numpy scan reassociates and its ulp
-                # drift can flip near-tied queue orders across rounds
-                for j, (a_j, s_j) in enumerate(zip(arr_o, svc_o)):
-                    st = a_j if a_j > d else d
-                    start_o[j] = st
-                    d = st + s_j
-                    dep_o[j] = d
-                dep[order] = dep_o
-                start[order] = start_o
-            ccuts: list = []
-            new = completion_chain(np, dep, flat["q_ri"],
-                                   flat["sg_resp"], flat["g_resp"],
-                                   flat["f_resp"], flat["c_resp"],
-                                   flat["lf"], flat["glob"],
-                                   flat["remote"], cuts=ccuts)
-            if np.array_equal(new, comp):
-                break
-            comp = new
-        else:
-            raise RuntimeError(
-                f"closed-loop sweep did not converge in {max_rounds} "
-                "rounds (host/LRU path); raise max_rounds")
-        comp_pt.append(comp)
-        t0_pt.append(t0)
-        pieces_pt.append(np.stack([cuts[0], cuts[1], arr, start, dep,
-                                   ccuts[0]]))
-    return comp_pt, t0_pt, pieces_pt
 
 
 def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
@@ -1048,16 +1053,6 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
             "--xla_force_host_platform_device_count=N before "
             "importing jax)")
 
-    if any(b["evict"] for b in built):
-        with span("run_sweep.host_rounds", spans):
-            comp_pt, t0_pt, pieces_pt = _closed_rounds_host(
-                built, capacity, seek, max_hops, max_rounds)
-        with span("run_sweep.fold", spans):
-            cols = _closed_fold(points, built, qs, comp_pt, t0_pt,
-                                pieces_pt)
-        return SweepResult(points, cols, walltime() - t_wall,
-                           dict(path="host", spans=spans))
-
     with span("run_sweep.build", spans):
         # one device block per shard; a single block runs unsharded
         D = min(devices, len(points))
@@ -1081,8 +1076,12 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
                     for k in padded[0][0]}
             aux = {k: np.stack([a[k] for _, a in padded])
                    for k in padded[0][1]}
+    # a static key of the program: a grid that cannot evict compiles
+    # without the stack-distance stage
+    evict = any(b["evict"] for b in built)
     with jax.enable_x64(True):
-        exe = _closed_exe(*args, R_max, Ls_max, D)
+        exe = _closed_exe(*args, R_max, Ls_max, D, evict,
+                          capacity if evict else 0)
         t_dev = walltime()
         # dispatch holds the transfers and the enqueue, and on a cold
         # call the trace and compile too; wait is the device's remainder
@@ -1097,6 +1096,7 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
             out = [np.asarray(o)[None] for o in out]
         comp_s, t0_s, done_s, counts_s, pieces_s = out
         rounds_s, changed_s = counts_s[:, 0], counts_s[:, 1]
+        misses = int(np.sum(counts_s[:, 2])) if evict else 0
         if exact:
             comp_s, t0_s, pieces_s = (f64bits.from_bits(v) for v in
                                       (comp_s, t0_s, pieces_s))
@@ -1118,7 +1118,7 @@ def _run_closed(points: List[SweepPoint], *, setting: str, seed: int,
         cols = _closed_fold(points, built, qs, comp_pt, t0_pt, pieces_pt)
     info = dict(path="device", devices=D, rounds=int(np.max(rounds_s)),
                 device_s=device_s, spans=spans,
-                changed=int(np.sum(changed_s)),
+                changed=int(np.sum(changed_s)), capacity_misses=misses,
                 op_rounds=sum(int(r) * b["n"]
                               for r, b in zip(rounds_s, blks)),
                 grid_slots=D * R_max * Ls_max)

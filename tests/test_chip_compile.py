@@ -53,14 +53,16 @@ def _shapes(tree, sharding):
                                        sharding=sharding), tree)
 
 
-def _closed_blocks(points, blocks):
+def _closed_blocks(points, blocks, cache_keys=None):
     """Per-block padded device inputs of a closed grid, as ``_run_closed``
-    lays them out, plus the round program's static arguments."""
-    svc = ServiceParams()
+    lays them out, plus the round program's static arguments; with
+    ``cache_keys``, leader page caches small enough to evict."""
+    svc = ServiceParams() if cache_keys is None else \
+        ServiceParams(page_cache_keys=cache_keys)
     dm = _DelayModel(SETTINGS["edge"], svc)
     built = [_closed_point_build(p, 0, dm, svc.page_cache_keys, 1)
              for p in points]
-    assert not any(b["evict"] for b in built)
+    assert any(b["evict"] for b in built) == (cache_keys is not None)
     blks = [_closed_assemble(built[d::blocks]) for d in range(blocks)]
     n = max(b["n"] for b in blks)
     R = max(len(b["rows"]) for b in blks)
@@ -90,6 +92,22 @@ def test_closed_round_program_compiles_for_v5e(one_chip):
     # the chip's compiler keeps the scan grid's fill a gather
     assert not [name for name in _op_names(compiled.as_text(), "scatter")
                 if "closed.to_grid" in name]
+
+
+def test_evicting_closed_round_program_compiles_for_v5e(one_chip):
+    """The same program with the exact LRU stack distance of a cache
+    smaller than the leaders' working sets (two int32 sorts and a
+    pairwise count in every round and the replay), and its third
+    count, the capacity misses."""
+    [(flat, aux)], static = _closed_blocks(
+        closed_grid(threads=8, ops=64)[:4], 1, cache_keys=8)
+    run = _closed_round_fn(*static, True, 8)
+    with jax.enable_x64(True):
+        compiled = jax.jit(run).lower(_shapes(flat, one_chip),
+                                      _shapes(aux, one_chip)).compile()
+    assert compiled.out_info[3].shape == (3,)   # rounds, changed, misses
+    assert [name for name in _op_names(compiled.as_text(), "sort")
+            if "closed.lru_stack" in name]
 
 
 def test_open_program_compiles_for_v5e(one_chip):

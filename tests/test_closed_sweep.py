@@ -3,17 +3,27 @@ batched fixed-point program must reproduce independent
 ``SimEdgeKV(engine="fast").run_closed_loop`` runs per grid point to
 <= 1e-9, in both LRU regimes, on every scan backend, and bit-identically
 when the point axis is sharded over multiple devices."""
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import pytest
 import jax
 
-from repro.sim import SimEdgeKV
+from repro.sim import SimEdgeKV, sweep
 from repro.sim.cluster import ServiceParams
+from repro.sim.network import SETTINGS
 from repro.sim.sweep import SweepPoint, closed_grid, run_sweep
+from repro.sim.vectorized import _DelayModel
 
 from test_sweep import (TOL, assert_point_matches, measured_speedup,
                         strict_perf_floor)
-from test_sweep_names import CONTENDED, _block
+from test_sweep_names import CONTENDED, _block, closed_rounds_host
+
+SRC = Path(__file__).resolve().parents[1] / "src"
 
 
 def closed_reference(p: SweepPoint, seed: int = 0,
@@ -91,15 +101,89 @@ def test_closed_sweep_cloud_setting_and_seed_offset():
 
 
 def test_closed_sweep_eviction_regime_matches_lru_replay():
-    """A page cache smaller than the working set forces the host-side
-    fixed point with the exact (Fenwick) LRU replay — still <= 1e-9."""
+    """A page cache smaller than the working set runs on the device
+    path, with the exact LRU stack distance in every round — still
+    <= 1e-9 against the fast engine's LRU replay."""
     svc = ServiceParams(page_cache_keys=16)
     pts = [SweepPoint(p_global=0.5, groups=3, threads=8, ops=64),
            SweepPoint(p_global=0.0, groups=3, threads=8, ops=64,
                       distribution="zipfian")]
     res = run_sweep(pts, loop="closed", seed=0, service=svc)
+    assert res.info["path"] == "device"
+    assert res.info["capacity_misses"] > 0
     for i, p in enumerate(pts):
         assert_point_matches(res.row(i), closed_reference(p, service=svc))
+
+
+def _stack_distances(keys: np.ndarray) -> list:
+    """Stack distance (distinct keys since the key's last use, itself
+    counted) of every op that reuses a key, plainly."""
+    out, last = [], {}
+    for i, k in enumerate(keys.tolist()):
+        if k in last:
+            out.append(len(set(keys[last[k] + 1:i].tolist())) + 1)
+        last[k] = i
+    return out
+
+
+def test_closed_sweep_eviction_ties_at_capacity():
+    """A small keyspace and a cache of 12 keys leave converged ops at
+    stack distance exactly 12 (hits) and 13 (misses); every column
+    still matches the fast engine."""
+    cap = 12
+    svc = ServiceParams(page_cache_keys=cap)
+    pts = [SweepPoint(p_global=0.5, groups=3, threads=8, ops=96,
+                      n_records=64),
+           SweepPoint(p_global=0.0, groups=3, threads=8, ops=96,
+                      n_records=64, distribution="zipfian")]
+    dm = _DelayModel(SETTINGS["edge"], svc)
+    built = [sweep._closed_point_build(p, 0, dm, cap, 1) for p in pts]
+    _, _, pieces = closed_rounds_host(
+        built, cap, float(dm.seek), max(b["max_hops"] for b in built), 256)
+    dist = [d for b, pc in zip(built, pieces) for m in b["rows"]
+            for d in _stack_distances(
+                b["flat"]["key"][m[np.argsort(pc[2][m], kind="stable")]])]
+    assert cap in dist and cap + 1 in dist
+    res = run_sweep(pts, loop="closed", seed=0, service=svc)
+    assert res.info["path"] == "device"
+    assert res.info["capacity_misses"] == sum(d > cap for d in dist)
+    for i, p in enumerate(pts):
+        assert_point_matches(res.row(i), closed_reference(p, service=svc))
+
+
+def test_evicting_sweep_sharded_over_two_devices():
+    """The evicting program sharded over the point axis, on two virtual
+    CPU devices in a process of its own, gives one device's bits."""
+    code = textwrap.dedent("""
+        import jax
+        import numpy as np
+        from repro.sim.cluster import ServiceParams
+        from repro.sim.sweep import SweepPoint, run_sweep
+        assert jax.local_device_count() == 2
+        svc = ServiceParams(page_cache_keys=12)
+        pts = [SweepPoint(p_global=pg, groups=3, threads=6, ops=60,
+                          n_records=64) for pg in (0.0, 0.5, 1.0)]
+        one = run_sweep(pts, loop="closed", seed=0, service=svc)
+        two = run_sweep(pts, loop="closed", seed=0, service=svc,
+                        devices=2)
+        assert two.info["path"] == "device" and two.info["devices"] == 2
+        assert one.info["capacity_misses"] == \
+            two.info["capacity_misses"] > 0
+        for k in one.columns:
+            assert np.array_equal(one.columns[k], two.columns[k],
+                                  equal_nan=True), k
+        print("bit-identical")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") + " --xla_force_"
+                          "host_platform_device_count=2").strip(),
+               PYTHONPATH=os.pathsep.join(
+                   [str(SRC)] + [p for p in [os.environ.get("PYTHONPATH")]
+                                 if p]))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip() == "bit-identical"
 
 
 def test_closed_sweep_pallas_backend_matches_assoc():

@@ -165,15 +165,18 @@ def test_open_sweep_stage_aggregates_match_fast_engine():
 
 @pytest.mark.parametrize("service_kw", [None, dict(page_cache_keys=16)])
 def test_closed_sweep_stage_aggregates_match_fast_engine(service_kw):
-    """Both closed-loop regimes — the fully batched jit fixed point and
-    the host-side eviction path — emit the same stage aggregates the
-    traced fast engine reconstructs, <= 1e-9."""
+    """Both closed-loop regimes — the cache-resident program and the
+    evicting one with its exact LRU stack distance, both on the device
+    path — emit the same stage aggregates the traced fast engine
+    reconstructs, <= 1e-9."""
     from repro.sim.cluster import ServiceParams
     svc = ServiceParams(**service_kw) if service_kw else None
     pts = [SweepPoint(p_global=0.5, groups=3, threads=8, ops=64,
                       distribution="zipfian"),
            SweepPoint(p_global=1.0, groups=5, threads=4, ops=40)]
     res = run_sweep(pts, loop="closed", seed=0, service=svc)
+    assert res.info["path"] == "device"
+    assert (res.info["capacity_misses"] > 0) == bool(service_kw)
     for i, p in enumerate(pts):
         sim = SimEdgeKV(setting="edge", seed=0, engine="fast", trace=True,
                         service=svc,
